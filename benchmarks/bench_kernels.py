@@ -1,10 +1,10 @@
-"""Kernel microbenchmarks: Pallas (interpret mode on CPU) vs pure-jnp oracle.
+"""Kernel microbenchmarks: Pallas kernels vs pure-jnp oracle.
 
-On this container Pallas runs in interpret mode, so wall-clock favors the
-jnp path — the deliverable here is CORRECTNESS at benchmark scale plus the
-op-count/fusion story (one fused kernel vs K+2 staged HBM round trips),
-with per-call timings for the jnp reference implementations that the
-serving/dry-run paths actually execute on CPU.
+The kernels compile to Mosaic on a TPU and run in the Pallas interpreter
+on any other backend.  Under the interpreter wall-clock favors the jnp
+path, so a CPU run checks CORRECTNESS at benchmark scale plus the
+op-count/fusion story (one fused kernel vs K+2 staged HBM round trips);
+its timings are not device numbers.
 """
 from __future__ import annotations
 

@@ -6,8 +6,8 @@ The sharded topology's two headline claims, measured at 256–4096 tenants:
     ~1/S with shard count S at fixed tenant count (the scaling move past
     ~10k tenants the ROADMAP flags);
   * **throughput** — the shard-bucketed ``shard_map`` dispatch must not
-    regress vs the dense single-replica banked kernel at S=1 (on this CPU
-    container both run the interpret-mode kernel; the S>1 numbers document
+    regress vs the dense single-replica banked kernel at S=1 (on a CPU
+    backend both run the interpreted kernel; the S>1 numbers there document
     the host-bucketing + launch overhead, not real-device scaling).
 
 Every configuration asserts BITWISE f32 parity against the dense kernel

@@ -92,6 +92,8 @@ def main() -> None:
     if args.check_mirrors:
         sys.exit(check_mirrors())
     quick = args.quick
+    from repro.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
 
     print("name,us_per_call,derived")
     t_all = time.perf_counter()

@@ -54,7 +54,8 @@ class TransformPipeline:
         fitted on (fitting on raw scores would mismatch the pipeline)."""
         corrected = transforms.posterior_correction(expert_scores, self.betas)
         w = self.weights / jnp.sum(self.weights)
-        return jnp.einsum("...k,k->...", corrected, w)
+        return jnp.einsum("...k,k->...", corrected, w,
+                          precision=jax.lax.Precision.HIGHEST)
 
     @property
     def num_experts(self) -> int:

@@ -13,7 +13,7 @@ update" = replacing these pytrees under a stable routing intent), and sharded.
 from __future__ import annotations
 
 import dataclasses
-from typing import Mapping, Sequence
+from typing import Any, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +85,8 @@ class Aggregation:
     def __call__(self, expert_scores: Array) -> Array:
         """``expert_scores``: (..., K) -> (...)."""
         w = self.weights / jnp.sum(self.weights)
-        return jnp.einsum("...k,k->...", expert_scores, w)
+        return jnp.einsum("...k,k->...", expert_scores, w,
+                          precision=jax.lax.Precision.HIGHEST)
 
     @staticmethod
     def uniform(k: int) -> "Aggregation":
@@ -232,7 +233,8 @@ def score_pipeline(
     """
     corrected = posterior_correction(expert_scores, betas)
     w = weights / jnp.sum(weights)
-    agg = jnp.einsum("...k,k->...", corrected, w)
+    agg = jnp.einsum("...k,k->...", corrected, w,
+                     precision=jax.lax.Precision.HIGHEST)
     return quantile_map(agg, src_quantiles, ref_quantiles)
 
 
@@ -515,8 +517,8 @@ class ShardedTransformBank:
     # --------------------------------------------------------- conversions
     @staticmethod
     def from_dense(bank: TransformBank, num_shards: int,
-                   shard_of: np.ndarray | None = None
-                   ) -> "ShardedTransformBank":
+                   shard_of: np.ndarray | None = None, *,
+                   sharding: Any = None) -> "ShardedTransformBank":
         """Partition a dense bank's rows over ``num_shards`` shards.
 
         ``shard_of`` (optional, (T,)) assigns each global row an owning
@@ -525,6 +527,9 @@ class ShardedTransformBank:
         even.  Local ids are assigned in global-row order within each shard;
         shards are padded to the max occupancy with identity rows
         (beta=1, weight=1, identity quantile table) that no request selects.
+        ``sharding`` (the dispatcher's tenant-axis ``NamedSharding``) places
+        each shard's rows on its own device; None leaves them on the
+        default device.
         """
         t = bank.num_rows
         assign, local, counts = shard_rows(t, num_shards, shard_of)
@@ -544,11 +549,12 @@ class ShardedTransformBank:
         weights[assign, local] = w_np
         src[assign, local] = qs_np
         ref[assign, local] = qr_np
+        betas, weights, src, ref = (jax.device_put(a, sharding)
+                                    for a in (betas, weights, src, ref))
         return ShardedTransformBank(
-            betas=jnp.asarray(betas), weights=jnp.asarray(weights),
-            src_quantiles=jnp.asarray(src), ref_quantiles=jnp.asarray(ref),
-            shard_of=assign, local_of=local, row_counts=counts,
-            generation=bank.generation)
+            betas=betas, weights=weights, src_quantiles=src,
+            ref_quantiles=ref, shard_of=assign, local_of=local,
+            row_counts=counts, generation=bank.generation)
 
     def shard_bank(self, shard: int) -> TransformBank:
         """The dense sub-bank one shard serves (its occupied local rows)."""

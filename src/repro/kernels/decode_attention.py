@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 Array = jax.Array
 
 
@@ -59,7 +61,7 @@ def _decode_kernel(vlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention(q: Array, k_cache: Array, v_cache: Array,
                      valid_len: Array, *, block_s: int = 512,
-                     interpret: bool = True) -> Array:
+                     interpret: bool | None = None) -> Array:
     """q: (B, Hq, D); caches: (B, S, Hkv, D); valid_len: (B,) -> (B, Hq, D)."""
     b, hq, d = q.shape
     s, hkv = k_cache.shape[1], k_cache.shape[2]
@@ -94,6 +96,6 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array,
             pltpu.VMEM((qpk,), jnp.float32),
             pltpu.VMEM((qpk, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(vlen, qg, kt, vt)
     return out.reshape(b, hq, d)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 Array = jax.Array
 
 
@@ -74,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 )
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                     sliding_window: int = 0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> Array:
+                    block_k: int = 128, interpret: bool | None = None) -> Array:
     """q: (B, Tq, Hq, D); k, v: (B, Tk, Hkv, D) -> (B, Tq, Hq, D)."""
     b, tq, hq, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
@@ -119,6 +121,6 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return jnp.moveaxis(out[:, :, :tq], 2, 1)         # (B, Tq, Hq, D)

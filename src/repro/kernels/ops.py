@@ -1,76 +1,19 @@
-"""Jit'd public entry points for the Pallas kernels.
+"""Public entry points for the Pallas kernels.
 
-On this CPU container every kernel runs in ``interpret=True`` (the Pallas
-interpreter executes the kernel body exactly); on TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to compile to Mosaic.
+Each kernel compiles to Mosaic on a TPU and runs in the Pallas interpreter
+on any other backend (see :func:`repro.kernels.resolve_interpret`); pass
+``interpret=`` to pin one or the other.
 """
 from __future__ import annotations
 
-import os
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.quantile_map import quantile_map
+from repro.kernels.score_pipeline import (
+    banked_skip_stats,
+    score_pipeline,
+    score_pipeline_banked,
+)
 
-import jax
-
-from repro.kernels import decode_attention as _da
-from repro.kernels import flash_attention as _fa
-from repro.kernels import quantile_map as _qm
-from repro.kernels import score_pipeline as _sp
-
-Array = jax.Array
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-
-def quantile_map(scores: Array, src_quantiles: Array, ref_quantiles: Array,
-                 *, block: int = _qm.DEFAULT_BLOCK,
-                 interpret: bool | None = None) -> Array:
-    return _qm.quantile_map(
-        scores, src_quantiles, ref_quantiles, block=block,
-        interpret=_INTERPRET if interpret is None else interpret,
-    )
-
-
-def score_pipeline(expert_scores: Array, betas: Array, weights: Array,
-                   src_quantiles: Array, ref_quantiles: Array,
-                   *, block: int = _sp.DEFAULT_BLOCK,
-                   interpret: bool | None = None) -> Array:
-    return _sp.score_pipeline(
-        expert_scores, betas, weights, src_quantiles, ref_quantiles,
-        block=block, interpret=_INTERPRET if interpret is None else interpret,
-    )
-
-
-def banked_skip_stats(tenant_idx, *, block: int = _sp.DEFAULT_BLOCK) -> dict:
-    """Host-side uniform-block fast-path report for a tenant layout (see
-    :func:`repro.kernels.score_pipeline.banked_skip_stats`)."""
-    return _sp.banked_skip_stats(tenant_idx, block=block)
-
-
-def score_pipeline_banked(expert_scores: Array, tenant_idx: Array,
-                          betas: Array, weights: Array,
-                          src_quantiles: Array, ref_quantiles: Array,
-                          *, block: int = _sp.DEFAULT_BLOCK,
-                          interpret: bool | None = None) -> Array:
-    return _sp.score_pipeline_banked(
-        expert_scores, tenant_idx, betas, weights, src_quantiles,
-        ref_quantiles, block=block,
-        interpret=_INTERPRET if interpret is None else interpret,
-    )
-
-
-def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
-                    sliding_window: int = 0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None) -> Array:
-    return _fa.flash_attention(
-        q, k, v, causal=causal, sliding_window=sliding_window,
-        block_q=block_q, block_k=block_k,
-        interpret=_INTERPRET if interpret is None else interpret,
-    )
-
-
-def decode_attention(q: Array, k_cache: Array, v_cache: Array,
-                     valid_len: Array, *, block_s: int = 512,
-                     interpret: bool | None = None) -> Array:
-    return _da.decode_attention(
-        q, k_cache, v_cache, valid_len, block_s=block_s,
-        interpret=_INTERPRET if interpret is None else interpret,
-    )
+__all__ = ["banked_skip_stats", "decode_attention", "flash_attention",
+           "quantile_map", "score_pipeline", "score_pipeline_banked"]

@@ -4,8 +4,8 @@ TPU adaptation (DESIGN.md §2/§6): the paper's O(log N) binary search is a
 branchy scalar loop — poison for the VPU.  Here the quantile tables (N <= 2048
 f32 values) sit in VMEM; the bucket index is a **branchless compare-and-sum**
 (one (BLOCK, N) vector compare + row reduction), and the four table lookups
-(q^S_i, q^S_{i+1}, q^R_i, q^R_{i+1}) become a single one-hot (BLOCK, N) x
-(N, 2) matmul on the MXU — no data-dependent control flow anywhere.
+(q^S_i, q^S_{i+1}, q^R_i, q^R_{i+1}) become one-hot masked row sums — no
+data-dependent control flow or addressing anywhere.
 
 Grid: 1-D over score blocks; tables are broadcast to every grid step.
 """
@@ -17,40 +17,50 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 Array = jax.Array
 
 DEFAULT_BLOCK = 1024
+
+
+def quantile_lookup(agg, qs, qr):
+    """Branchless T^Q (Eq. 4) of ``agg`` (BLOCK,) against per-row tables
+    ``qs``/``qr`` of shape (1, N) (one table for the block) or (BLOCK, N).
+
+    The bucket index is a compare-and-sum; the four table reads are one-hot
+    masked row sums (exact: one nonzero term each), and the clip bounds are
+    the tables' first and last columns taken as static slices — no
+    data-dependent addressing, so Mosaic lowers it as plain vector ops."""
+    n = qs.shape[-1]
+    ge = (agg[:, None] >= qs).astype(jnp.float32)
+    idx = jnp.clip(jnp.sum(ge, axis=-1) - 1.0, 0.0, n - 2.0)
+    iota_n = jax.lax.broadcasted_iota(
+        jnp.int32, (agg.shape[0], n), 1).astype(jnp.float32)
+    onehot_i = (iota_n == idx[:, None]).astype(jnp.float32)
+    onehot_ip1 = (iota_n == (idx + 1.0)[:, None]).astype(jnp.float32)
+    q_s_i = jnp.sum(onehot_i * qs, axis=-1)
+    q_s_n = jnp.sum(onehot_ip1 * qs, axis=-1)
+    q_r_i = jnp.sum(onehot_i * qr, axis=-1)
+    q_r_n = jnp.sum(onehot_ip1 * qr, axis=-1)
+    denom = jnp.where(q_s_n - q_s_i > 0, q_s_n - q_s_i, 1.0)
+    out = q_r_i + (agg - q_s_i) * (q_r_n - q_r_i) / denom
+    lo = jnp.sum(qr[:, :1], axis=-1)
+    hi = jnp.sum(qr[:, n - 1:], axis=-1)
+    return jnp.clip(out, lo, hi)
 
 
 def _quantile_map_kernel(scores_ref, src_ref, ref_ref, out_ref):
     s = scores_ref[...].astype(jnp.float32)          # (BLOCK,)
     qs = src_ref[...].astype(jnp.float32)            # (N,)
     qr = ref_ref[...].astype(jnp.float32)            # (N,)
-    n = qs.shape[-1]
-
-    # branchless bucket search: idx = #(q_i <= s) - 1, clipped to [0, N-2]
-    ge = (s[:, None] >= qs[None, :]).astype(jnp.float32)   # (BLOCK, N)
-    idx = jnp.clip(jnp.sum(ge, axis=-1) - 1.0, 0.0, n - 2.0)
-
-    # one-hot gather of the 4 table values as 2 MXU matvecs
-    iota = jax.lax.broadcasted_iota(jnp.float32, (s.shape[0], n), 1)
-    onehot_i = (iota == idx[:, None]).astype(jnp.float32)        # (BLOCK, N)
-    onehot_ip1 = (iota == (idx + 1.0)[:, None]).astype(jnp.float32)
-    tables = jnp.stack([qs, qr], axis=-1)                        # (N, 2)
-    lo = onehot_i @ tables                                       # (BLOCK, 2)
-    hi = onehot_ip1 @ tables
-    q_s_i, q_r_i = lo[:, 0], lo[:, 1]
-    q_s_n, q_r_n = hi[:, 0], hi[:, 1]
-
-    denom = jnp.where(q_s_n - q_s_i > 0, q_s_n - q_s_i, 1.0)
-    out = q_r_i + (s - q_s_i) * (q_r_n - q_r_i) / denom
-    out = jnp.clip(out, qr[0], qr[-1])
-    out_ref[...] = out.astype(out_ref.dtype)
+    out_ref[...] = quantile_lookup(s, qs[None, :], qr[None, :]).astype(
+        out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def quantile_map(scores: Array, src_quantiles: Array, ref_quantiles: Array,
-                 *, block: int = DEFAULT_BLOCK, interpret: bool = True) -> Array:
+                 *, block: int = DEFAULT_BLOCK, interpret: bool | None = None) -> Array:
     """Flat or batched scores -> mapped scores (same shape/dtype)."""
     shape = scores.shape
     flat = scores.reshape(-1)
@@ -72,6 +82,6 @@ def quantile_map(scores: Array, src_quantiles: Array, ref_quantiles: Array,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((total,), scores.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(flat, src_quantiles, ref_quantiles)
     return out[:n].reshape(shape)

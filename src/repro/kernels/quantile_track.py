@@ -38,8 +38,10 @@ Exactness contract (why replay is bitwise, not approximate):
   ``tests/test_device_tracking.py``).
 * Scatter indices are ``slot * capacity + pending + within-window rank`` —
   unique by construction, so the scatter is deterministic and needs no
-  device RNG (``unique_indices=True`` + ``mode="promise_in_bounds"`` are
-  safe and let XLA skip the dedup/clamp paths).
+  device RNG (``unique_indices=True`` lets XLA skip the dedup path).
+* Windows are padded to a power-of-two row count, so the set of compiled
+  programs stays bounded (one per bucket, warmed by :meth:`warm`); pad rows
+  scatter past the end of the plane and are dropped.
 
 Host pulls happen ONLY at the calibration boundary: a stream spills when its
 staging would overflow, and the calibration plane (Eq.-5 gating, snapshots,
@@ -72,10 +74,10 @@ def _fused_append(staging: jax.Array, flat_idx: jax.Array,
     ``staging`` is the flat ``(slots * capacity,)`` f32 staging plane
     (donated: updated in place, the caller rebinds the result).  The
     aggregate is the inlined ``_banked_pre_quantile`` jaxpr — bitwise the
-    value the eager host path would have pulled."""
+    value the eager host path would have pulled.  Out-of-range indices
+    (bucket padding) are dropped."""
     agg = _banked_pre_quantile(expert_scores, tenant_idx, betas, weights)
-    return staging.at[flat_idx].set(
-        agg, mode="promise_in_bounds", unique_indices=True)
+    return staging.at[flat_idx].set(agg, mode="drop", unique_indices=True)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -84,8 +86,22 @@ def _staged_append(staging: jax.Array, flat_idx: jax.Array,
     """Scatter an already-computed aggregate (tiered stores compute
     ``pre_quantile`` against host-paged rows, so only the append fuses)."""
     return staging.at[flat_idx].set(
-        jnp.asarray(agg, jnp.float32),
-        mode="promise_in_bounds", unique_indices=True)
+        jnp.asarray(agg, jnp.float32), mode="drop", unique_indices=True)
+
+
+def _bucketed(flat_idx: np.ndarray, size: int, *rows: np.ndarray
+              ) -> tuple[np.ndarray, ...]:
+    """Pad a window to the next power-of-two row count: pad indices point
+    past the ``size``-element plane (dropped by the scatter), pad rows
+    repeat the last real row."""
+    b = len(flat_idx)
+    pad = (1 << max(b - 1, 0).bit_length()) - b
+    if not pad:
+        return (flat_idx, *rows)
+    idx = np.concatenate([flat_idx, size + np.arange(pad, dtype=np.int64)])
+    return (idx.astype(np.int32),
+            *(np.concatenate([r, np.repeat(r[-1:], pad, axis=0)])
+              for r in rows))
 
 
 def _segment_plan(slots: np.ndarray) -> tuple[np.ndarray, np.ndarray,
@@ -205,10 +221,12 @@ class DeviceQuantileTracker:
         flat_idx = self._plan(keys)
         if flat_idx is None:
             return False
+        flat_idx, raws, tenant_idx = _bucketed(
+            flat_idx, self._staging.size, np.asarray(raws, np.float32),
+            np.asarray(tenant_idx, np.int32))
         self._staging = _fused_append(
-            self._staging, jnp.asarray(flat_idx),
-            jnp.asarray(raws, jnp.float32), jnp.asarray(tenant_idx),
-            bank.betas, bank.weights)
+            self._staging, jnp.asarray(flat_idx), jnp.asarray(raws),
+            jnp.asarray(tenant_idx), bank.betas, bank.weights)
         return True
 
     def append_agg(self, keys: list[tuple], agg: Any) -> bool:
@@ -219,9 +237,27 @@ class DeviceQuantileTracker:
         flat_idx = self._plan(keys)
         if flat_idx is None:
             return False
+        flat_idx, agg = _bucketed(flat_idx, self._staging.size,
+                                  np.asarray(agg, np.float32))
         self._staging = _staged_append(
             self._staging, jnp.asarray(flat_idx), jnp.asarray(agg))
         return True
+
+    def warm(self, rows: int, bank: Any = None) -> None:
+        """Compile the append program for ``rows``-row windows against
+        ``bank`` (the fused program) or for precomputed aggregates (None),
+        on a scratch plane: no stream, count or staged sample changes."""
+        size = self._staging.size
+        k = 1 if bank is None else bank.betas.shape[-1]
+        idx, raws, tid = _bucketed(
+            (size + np.arange(rows)).astype(np.int32), size,  # all dropped
+            np.zeros((rows, k), np.float32), np.zeros(rows, np.int32))
+        scratch = jnp.zeros_like(self._staging)
+        if bank is None:
+            _staged_append(scratch, jnp.asarray(idx), jnp.asarray(raws[:, 0]))
+        else:
+            _fused_append(scratch, jnp.asarray(idx), jnp.asarray(raws),
+                          jnp.asarray(tid), bank.betas, bank.weights)
 
     # ---------------------------------------------------------------- drain
     def _drain_slots(self, slots: Any) -> int:

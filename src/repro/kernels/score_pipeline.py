@@ -4,9 +4,9 @@
                                      aggregation -> quantile map
 
 One VMEM pass over a (BLOCK, K) score tile instead of K+2 HBM round trips:
-the correction is elementwise, the aggregation a (BLOCK,K)x(K,) matvec, and
-the quantile map reuses the branchless compare-and-sum + one-hot-matmul
-lookup of kernels/quantile_map.py.  This kernel IS the paper's transformation
+the correction is elementwise, the aggregation a K-term row sum, and the
+quantile map reuses the branchless compare-and-sum + one-hot lookup of
+kernels/quantile_map.py.  This kernel IS the paper's transformation
 DAG as a single fused op — the serving hot path for every scored event.
 
 Two entry points:
@@ -40,9 +40,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+from repro.kernels.quantile_map import quantile_lookup
+
 Array = jax.Array
 
 DEFAULT_BLOCK = 1024
+# scoped-VMEM budget of the banked kernel: the mixed-block path holds the
+# (BLOCK, T) one-hot selector, the resident banks and their exact-precision
+# gather operands at once (~21 MiB at BLOCK=1024, T=1024, N=256 — over
+# Mosaic's 16 MiB default, well under a v5e core's 128 MiB)
+BANKED_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _round_block(n: int, block: int) -> int:
@@ -65,31 +73,19 @@ def _score_pipeline_kernel(scores_ref, betas_ref, weights_ref, src_ref,
     # --- T^C: posterior correction (Eq. 3), elementwise on the VPU
     corrected = beta[None, :] * y / (1.0 - (1.0 - beta[None, :]) * y)
 
-    # --- A: weighted average (self-normalizing), one matvec
+    # --- A: weighted average (self-normalizing), a K-term row sum
     w_norm = w / jnp.sum(w)
-    agg = corrected @ w_norm                          # (BLOCK,)
+    agg = jnp.sum(corrected * w_norm[None, :], axis=-1)   # (BLOCK,)
 
     # --- T^Q: branchless piecewise-linear quantile map (Eq. 4)
-    n = qs.shape[-1]
-    ge = (agg[:, None] >= qs[None, :]).astype(jnp.float32)
-    idx = jnp.clip(jnp.sum(ge, axis=-1) - 1.0, 0.0, n - 2.0)
-    iota = jax.lax.broadcasted_iota(jnp.float32, (agg.shape[0], n), 1)
-    onehot_i = (iota == idx[:, None]).astype(jnp.float32)
-    onehot_ip1 = (iota == (idx + 1.0)[:, None]).astype(jnp.float32)
-    tables = jnp.stack([qs, qr], axis=-1)
-    lo = onehot_i @ tables
-    hi = onehot_ip1 @ tables
-    q_s_i, q_r_i = lo[:, 0], lo[:, 1]
-    q_s_n, q_r_n = hi[:, 0], hi[:, 1]
-    denom = jnp.where(q_s_n - q_s_i > 0, q_s_n - q_s_i, 1.0)
-    out = q_r_i + (agg - q_s_i) * (q_r_n - q_r_i) / denom
-    out_ref[...] = jnp.clip(out, qr[0], qr[-1]).astype(out_ref.dtype)
+    out_ref[...] = quantile_lookup(agg, qs[None, :], qr[None, :]).astype(
+        out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def score_pipeline(expert_scores: Array, betas: Array, weights: Array,
                    src_quantiles: Array, ref_quantiles: Array,
-                   *, block: int = DEFAULT_BLOCK, interpret: bool = True
+                   *, block: int = DEFAULT_BLOCK, interpret: bool | None = None
                    ) -> Array:
     """expert_scores: (..., K) -> (...) business-ready scores."""
     *batch_shape, k = expert_scores.shape
@@ -114,7 +110,7 @@ def score_pipeline(expert_scores: Array, betas: Array, weights: Array,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((total,), expert_scores.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(flat, betas, weights, src_quantiles, ref_quantiles)
     return out[:n].reshape(batch_shape)
 
@@ -136,19 +132,7 @@ def _score_pipeline_banked_kernel(btenant_ref, uniform_ref, scores_ref,
         w_norm = w / jnp.sum(w, axis=-1, keepdims=True)
         agg = jnp.sum(corrected * w_norm, axis=-1)              # (BLOCK,)
         # --- T^Q: branchless quantile map against per-row tables (Eq. 4)
-        n = qs.shape[-1]
-        ge = (agg[:, None] >= qs).astype(jnp.float32)
-        idx = jnp.clip(jnp.sum(ge, axis=-1) - 1.0, 0.0, n - 2.0)
-        iota_n = jax.lax.broadcasted_iota(jnp.float32, (agg.shape[0], n), 1)
-        onehot_i = (iota_n == idx[:, None]).astype(jnp.float32)
-        onehot_ip1 = (iota_n == (idx + 1.0)[:, None]).astype(jnp.float32)
-        q_s_i = jnp.sum(onehot_i * qs, axis=-1)
-        q_s_n = jnp.sum(onehot_ip1 * qs, axis=-1)
-        q_r_i = jnp.sum(onehot_i * qr, axis=-1)
-        q_r_n = jnp.sum(onehot_ip1 * qr, axis=-1)
-        denom = jnp.where(q_s_n - q_s_i > 0, q_s_n - q_s_i, 1.0)
-        out = q_r_i + (agg - q_s_i) * (q_r_n - q_r_i) / denom
-        out_ref[...] = jnp.clip(out, qr[:, 0], qr[:, -1]).astype(out_ref.dtype)
+        out_ref[...] = quantile_lookup(agg, qs, qr).astype(out_ref.dtype)
 
     @pl.when(uniform_ref[b] == 1)
     def _uniform_block():
@@ -158,24 +142,29 @@ def _score_pipeline_banked_kernel(btenant_ref, uniform_ref, scores_ref,
         # prefetched SMEM scalars, available before the block body runs.
         t0 = btenant_ref[b]
         row = (pl.ds(t0, 1), slice(None))
-        finish(pl.load(betas_ref, row).astype(jnp.float32),
-               pl.load(weights_ref, row).astype(jnp.float32),
-               pl.load(src_ref, row).astype(jnp.float32),
-               pl.load(ref_ref, row).astype(jnp.float32))
+        finish(betas_ref[row].astype(jnp.float32),
+               weights_ref[row].astype(jnp.float32),
+               src_ref[row].astype(jnp.float32),
+               ref_ref[row].astype(jnp.float32))
 
     @pl.when(uniform_ref[b] == 0)
     def _mixed_block():
         # general path: gather each row's (tenant, predictor) parameters
         # with a one-hot (BLOCK, T) matmul per (T, ·) bank — dense and
-        # MXU-friendly, no data-dependent addressing.
+        # MXU-friendly, no data-dependent addressing.  HIGHEST precision
+        # keeps the gather exact on the MXU (a default-precision f32 matmul
+        # would round the gathered parameters to bf16).
         tid = idx_ref[...].astype(jnp.int32)         # (BLOCK,)
         t = betas_ref.shape[0]
         iota_t = jax.lax.broadcasted_iota(jnp.int32, (y.shape[0], t), 1)
         sel = (iota_t == tid[:, None]).astype(jnp.float32)      # (BLOCK, T)
-        finish(sel @ betas_ref[...].astype(jnp.float32),        # (BLOCK, K)
-               sel @ weights_ref[...].astype(jnp.float32),
-               sel @ src_ref[...].astype(jnp.float32),          # (BLOCK, N)
-               sel @ ref_ref[...].astype(jnp.float32))
+
+        def gather(ref):
+            return jnp.dot(sel, ref[...].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
+
+        finish(gather(betas_ref), gather(weights_ref),          # (BLOCK, K)
+               gather(src_ref), gather(ref_ref))                # (BLOCK, N)
 
 
 def _block_summary(idx_flat: Array, block: int) -> tuple[Array, Array]:
@@ -212,7 +201,7 @@ def score_pipeline_banked(expert_scores: Array, tenant_idx: Array,
                           betas: Array, weights: Array,
                           src_quantiles: Array, ref_quantiles: Array,
                           *, block: int = DEFAULT_BLOCK,
-                          interpret: bool = True) -> Array:
+                          interpret: bool | None = None) -> Array:
     """Mixed-tenant Eq. 2 in ONE ``pallas_call``.
 
     ``expert_scores``: (..., K) raw scores; ``tenant_idx``: (...) int32 row
@@ -264,7 +253,9 @@ def score_pipeline_banked(expert_scores: Array, tenant_idx: Array,
         _score_pipeline_banked_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((total,), expert_scores.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=BANKED_VMEM_LIMIT),
+        interpret=resolve_interpret(interpret),
     )(btenant, uniform, flat, idx_flat, betas, weights,
       src_quantiles, ref_quantiles)
     return out[:n].reshape(batch_shape)

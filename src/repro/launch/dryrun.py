@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import jax_compat
 from repro.configs import ARCH_IDS, applicable_shapes
 from repro.launch import mesh as mesh_lib
 from repro.launch import roofline, shardings, specs
@@ -97,7 +96,7 @@ def build_shardings(bundle: specs.StepBundle, mesh, *,
             for k, v in inputs.items()
         }
         # step may carry bare-PartitionSpec constraints / shard_map
-        with mesh, jax_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = jax.eval_shape(bundle.step_fn, *bundle.abstract_args)
         out_sh = {}
         if "logits" in out:
@@ -173,7 +172,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
                               **build_kwargs)
     in_sh, out_sh, fsdp_used = build_shardings(bundle, mesh, fsdp=fsdp)
 
-    with mesh, jax_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             bundle.step_fn,
             in_shardings=in_sh,

@@ -37,9 +37,8 @@ def make_tenant_mesh(num_shards: int) -> jax.sharding.Mesh:
     tiered-over-sharded topology, one bounded hot-tier/victim-cache view of
     its shard's host rows (``serving/tiering.ShardedTieredBankStore``); the
     serving layer buckets requests by owning shard and launches the banked
-    kernel per shard via ``shard_map`` over this axis.  Goes through the
-    jax_compat shim so the same call works on jax 0.4.x and the newest
-    surface.
+    kernel per shard via ``shard_map`` over this axis.  The axis is
+    ``Auto``: shardings over it are placement, not part of array types.
     """
     if num_shards < 1:
         raise ValueError(f"num_shards must be >= 1, got {num_shards}")
@@ -48,8 +47,8 @@ def make_tenant_mesh(num_shards: int) -> jax.sharding.Mesh:
         raise ValueError(
             f"tenant mesh needs {num_shards} devices, have {avail} "
             "(CI: XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-    from repro import jax_compat
-    return jax_compat.make_mesh((num_shards,), ("tenants",))
+    return jax.make_mesh((num_shards,), ("tenants",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def tenant_axis_size(mesh: jax.sharding.Mesh) -> int:

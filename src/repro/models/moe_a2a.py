@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
 from repro.models import layers
 from repro.models.config import MoEConfig
 
@@ -64,7 +63,7 @@ def moe_forward_a2a(
     """x: (B, T, d) -> (out, aux). Must run under ``jax.set_mesh(mesh)``."""
     b, t, d = x.shape
     e, k = mo.num_experts, mo.top_k
-    mesh = jax_compat.get_active_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if model_axis not in mesh.shape:
         raise RuntimeError(
             "moe impl='a2a' needs the production mesh via jax.set_mesh(...)")
@@ -81,8 +80,8 @@ def moe_forward_a2a(
     )
     out_specs = (P(data_axes, model_axis, None), P())
 
-    @jax_compat.shard_map(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
+    @jax.shard_map(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                   check_vma=False)
     def inner(router_w, gate_w, up_w, down_w, xl):
         bl, tl, _ = xl.shape
         n_local = bl * tl

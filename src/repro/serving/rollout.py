@@ -283,8 +283,8 @@ class RollingUpdate:
             warmup_mod.warm_up(new.server, self.schema_dim,
                                batch_sizes=self.warmup_batch_sizes)
             new.warmup_seconds = time.perf_counter() - t0
-            # tiered topology: a surged replica starts with EMPTY tiers (the
-            # warm-up calls predictors directly, never the banked path) —
+            # tiered topology: a surged replica starts with COLD tiers (the
+            # warm-up compiles the banked path but serves no tenant row) —
             # adopt the victim replica's hotness/admission state so the new
             # replica's first windows hit a promoted hot set instead of
             # paging its whole working set through the victim cache.

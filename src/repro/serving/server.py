@@ -59,9 +59,8 @@ from typing import Any, Callable, Mapping
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
-from repro import jax_compat
 from repro.core.predictor import Predictor, PredictorSpec, deploy_predictor
 from repro.core.quantiles import StreamingQuantileEstimator, required_sample_size
 from repro.core.registry import ModelPool
@@ -257,7 +256,14 @@ class ShardedBankDispatcher:
     def __init__(self, mesh: Any, *, fused: bool = True) -> None:
         self.mesh = mesh
         self.fused = fused
+        # placement of every (S, ·) launch operand: row s on shard s's device
+        self.sharding = NamedSharding(mesh, PartitionSpec(TENANT_AXIS))
         self._launch_fn: Any = None
+
+    @property
+    def devices(self) -> list:
+        """Shard s's device, in shard order."""
+        return list(self.mesh.devices.flat)
 
     def _launch(self) -> Any:
         if self._launch_fn is None:
@@ -269,7 +275,7 @@ class ShardedBankDispatcher:
                 return impl(sc[0], ti[0], b[0], w[0], qs[0], qr[0])[None]
 
             spec = PartitionSpec(TENANT_AXIS)
-            self._launch_fn = jax.jit(jax_compat.shard_map(
+            self._launch_fn = jax.jit(jax.shard_map(
                 per_shard, mesh=self.mesh, in_specs=(spec,) * 6,
                 out_specs=spec, check_vma=False))
         return self._launch_fn
@@ -286,10 +292,10 @@ class ShardedBankDispatcher:
         slot-remapped buckets itself and calls this directly with its
         stacked per-shard tier views — same mesh, same compiled launch.
         """
-        with self.mesh:
-            return np.asarray(self._launch()(
-                jnp.asarray(packed), jnp.asarray(pidx), betas,
-                weights, src_quantiles, ref_quantiles))
+        return np.asarray(self._launch()(
+            jax.device_put(packed, self.sharding),
+            jax.device_put(pidx, self.sharding), betas,
+            weights, src_quantiles, ref_quantiles))
 
     def _run(self, packed: np.ndarray, pidx: np.ndarray,
              sbank: ShardedTransformBank) -> np.ndarray:
@@ -473,8 +479,13 @@ class MuseServer:
                                       else 0)
             predictors = dict(plane.predictors)
             predictors[spec.name] = pred
-            self._plane = dataclasses.replace(plane, predictors=predictors,
-                                              generation=gen)
+            # a model group's bank holds every predictor of the group, so
+            # the group's cached banks no longer match its row set
+            banks = {k: v for k, v in dict(plane.banks).items()
+                     if plane.predictors[k[0]].model_names
+                     != pred.model_names}
+            self._plane = dataclasses.replace(
+                plane, predictors=predictors, banks=banks, generation=gen)
             self.metrics["bank_generation"] = gen
         return pred
 
@@ -644,7 +655,8 @@ class MuseServer:
                      for p in pipelines], generation=gen)
             if sharded is None and self._sharded_dispatch is not None:
                 sharded = ShardedTransformBank.from_dense(
-                    bank, self.config.tenant_shards)
+                    bank, self.config.tenant_shards,
+                    sharding=self._sharded_dispatch.sharding)
             new_banks[key] = _BankEntry(pipelines, bank, sharded)
 
         # the publish point: ONE whole-plane swap, never in-place edits
@@ -665,6 +677,15 @@ class MuseServer:
         expert-model set batch together — one executable call plus one
         banked kernel dispatch serves the whole window."""
         return self.group_key(self.routing.resolve(intent))
+
+    @staticmethod
+    def bank_names(pred_names, plane: _ControlPlane) -> tuple[str, ...]:
+        """A window's bank key: every deployed predictor of its model
+        group, sorted.  One bank (one kernel shape) serves the group
+        whatever subset of its predictors a window carries."""
+        group = plane.predictors[pred_names[0]].model_names
+        return tuple(sorted(n for n, p in plane.predictors.items()
+                            if p.model_names == group))
 
     def group_key(self, resolution) -> str:
         """``batch_key`` for an already-resolved intent — the async engine
@@ -740,7 +761,8 @@ class MuseServer:
         sharded = None
         if self._sharded_dispatch is not None:
             sharded = ShardedTransformBank.from_dense(
-                bank, self.config.tenant_shards)
+                bank, self.config.tenant_shards,
+                sharding=self._sharded_dispatch.sharding)
         entry = _BankEntry(pipelines, bank, sharded)
         plane.banks[names] = entry
         return entry
@@ -808,8 +830,7 @@ class MuseServer:
         Returns the (B, K) raw-score matrix.
         """
         plane = self._plane if plane is None else plane
-        bank_names = tuple(sorted(set(pred_names)))
-        pred0 = plane.predictors[bank_names[0]]
+        pred0 = plane.predictors[pred_names[0]]
         group = pred0.model_names
         dim = self._model_dim(pred0) or len(requests[idxs[0]].features)
         rows: list[np.ndarray | None] = [None] * len(idxs)
@@ -873,7 +894,7 @@ class MuseServer:
         window's provenance stamp.
         """
         plane = self._plane if plane is None else plane
-        bank_names = tuple(sorted(set(pred_names)))  # canonical cache key
+        bank_names = self.bank_names(pred_names, plane)  # cache key
         entry = self._bank_for(bank_names, plane)
         row_of = {n: r for r, n in enumerate(bank_names)}
         tenant_idx = np.asarray([row_of[n] for n in pred_names], np.int32)
@@ -1204,6 +1225,57 @@ class MuseServer:
             ref_quantiles=jnp.asarray(np.asarray(ref_quantiles), jnp.float32),
         )
 
+    # ---------------------------------------------------------------- warm-up
+    def warm_dispatch(self, window_sizes) -> int:
+        """Compile the transform and track programs that windows of these
+        sizes reach, for every model group, before live traffic does.
+
+        Each window size warms its shape bucket; a sharded or tiered
+        group also warms every smaller bucket (per-shard buckets and
+        multi-pass sub-windows are at most the window's).  Inert rows run
+        against the live banks: no metric, hotness count, estimator
+        stream or staged sample changes.  Returns the programs run."""
+        plane = self._plane
+        sizes = [int(b) for b in window_sizes if b > 0]
+        if not sizes or not plane.predictors:
+            return 0
+        top = _shape_bucket(max(sizes))
+        every = [1 << i for i in range(top.bit_length())]
+        windows = sorted({_shape_bucket(b) for b in sizes})
+        groups = sorted({self.bank_names([n], plane)
+                         for n in plane.predictors})
+        runs = 0
+        for names in groups:
+            entry = self._bank_for(names, plane)
+            k = len(plane.predictors[names[0]].model_names)
+            spread = entry.tiered is not None or entry.sharded is not None
+            for b in every if spread else windows:
+                raws = np.zeros((b, k), np.float32)
+                tid = np.zeros(b, np.int32)
+                if entry.tiered is not None:
+                    entry.tiered.warm(raws)
+                elif entry.sharded is not None:
+                    s = entry.sharded.num_shards
+                    self._sharded_dispatch.run_packed(
+                        np.zeros((s, b, k), np.float32),
+                        np.zeros((s, b), np.int32), entry.sharded.betas,
+                        entry.sharded.weights, entry.sharded.src_quantiles,
+                        entry.sharded.ref_quantiles)
+                elif self.config.fused_kernel:
+                    bank = entry.bank
+                    np.asarray(ops.score_pipeline_banked(
+                        jnp.asarray(raws), jnp.asarray(tid), bank.betas,
+                        bank.weights, bank.src_quantiles,
+                        bank.ref_quantiles))
+                else:
+                    np.asarray(entry.bank(jnp.asarray(raws),
+                                          jnp.asarray(tid)))
+                if self._tracker is not None:
+                    with self._estimator_lock:
+                        self._tracker.warm(b, entry.bank)
+                runs += 1
+        return runs
+
     # ----------------------------------------------------- tiering control
     @property
     def prefetch_enabled(self) -> bool:
@@ -1229,10 +1301,10 @@ class MuseServer:
         if self.config.tiering is None or not pred_names:
             return 0
         plane = self._plane if plane is None else plane
-        names = tuple(sorted(set(pred_names)))
+        if any(n not in plane.predictors for n in pred_names):
+            return 0
+        names = self.bank_names(pred_names, plane)
         if create:
-            if any(n not in plane.predictors for n in names):
-                return 0
             store = self._bank_for(names, plane).tiered
         else:
             with self._tier_lock:

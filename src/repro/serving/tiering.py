@@ -101,6 +101,7 @@ import dataclasses
 import threading
 from typing import Any, Mapping, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -335,7 +336,8 @@ class TieredBankStore:
 
     def __init__(self, host: HostBankStore,
                  config: TieringConfig | None = None, *,
-                 generation: int = 0, hot_slots: int | None = None) -> None:
+                 generation: int = 0, hot_slots: int | None = None,
+                 device: Any = None) -> None:
         self.host = host
         self.config = config or TieringConfig()
         t = host.num_rows
@@ -374,9 +376,12 @@ class TieredBankStore:
                 (np.asarray(ps), np.asarray(pr)), n)
             src[-1] = np.asarray(ps)
             ref[-1] = np.asarray(pr)
+        # ``device``: where the view lives (the composed sharded store puts
+        # each shard's view on that shard's device; None = default device).
+        # Staging and publish scatters keep it there.
         self._view = _TierView(
-            jnp.asarray(betas), jnp.asarray(weights),
-            jnp.asarray(src), jnp.asarray(ref), generation)
+            *(jax.device_put(a, device) for a in (betas, weights, src, ref)),
+            generation)
         # RLock: the composed sharded store holds every shard's lock and
         # then calls per-shard methods that re-acquire their own
         self._lock = threading.RLock()
@@ -421,6 +426,11 @@ class TieredBankStore:
         """Device-resident bank bytes — a function of CONFIGURED capacity
         (hot + victim + prior row), independent of ``num_rows``."""
         return self._view.nbytes
+
+    @property
+    def devices(self) -> set:
+        """The device(s) holding the tier view."""
+        return self._view.betas.devices()
 
     @property
     def host_bytes(self) -> int:
@@ -538,6 +548,12 @@ class TieredBankStore:
                    view.betas, view.weights,
                    view.src_quantiles, view.ref_quantiles)
         return np.asarray(out)[:b]
+
+    def warm(self, raws: np.ndarray) -> None:
+        """Compile the kernel for this window's bucket (inert prior-slot
+        rows; no hotness or staging side effects)."""
+        self._score_slots(raws, np.full(len(raws), self._prior_slot,
+                                        np.int32), self._view)
 
     # -------------------------------------------------------------- serving
     def dispatch(self, expert_scores: np.ndarray, tenant_idx: np.ndarray
@@ -910,16 +926,6 @@ class ShardedTieredBankStore:
         # ones) so the per-shard views stack into one (S, R, ·) operand
         hot_slots = min(self.config.hot_capacity,
                         max(int(counts.max()) if counts.size else 1, 1))
-        self.shards: list[TieredBankStore] = []
-        for s in range(num_shards):
-            g = self.global_of[s]
-            sub = HostBankStore(
-                host.betas[g], host.weights[g],
-                host.src_quantiles[g], host.ref_quantiles[g],
-                admitted=host.admitted[g])
-            self.shards.append(TieredBankStore(
-                sub, self.config, generation=generation,
-                hot_slots=hot_slots))
         if dispatcher is None:
             # deferred: serving.server imports this module at the top
             from repro.serving.server import ShardedBankDispatcher
@@ -929,6 +935,20 @@ class ShardedTieredBankStore:
             dispatcher = ShardedBankDispatcher(
                 mesh, fused=self.config.fused_kernel)
         self.dispatcher = dispatcher
+        # each shard's tier view lives on that shard's device, so the
+        # stacked launch operand assembles without a copy (a stand-in
+        # dispatcher without devices leaves views on the default device)
+        devices = getattr(dispatcher, "devices", None) or [None] * num_shards
+        self.shards: list[TieredBankStore] = []
+        for s in range(num_shards):
+            g = self.global_of[s]
+            sub = HostBankStore(
+                host.betas[g], host.weights[g],
+                host.src_quantiles[g], host.ref_quantiles[g],
+                admitted=host.admitted[g])
+            self.shards.append(TieredBankStore(
+                sub, self.config, generation=generation,
+                hot_slots=hot_slots, device=devices[s]))
         # identity witness for the serving layer's bank cache (same
         # contract as TieredBankStore.source_pipelines)
         self.source_pipelines: tuple | None = None
@@ -1047,11 +1067,19 @@ class ShardedTieredBankStore:
         if self._stacked is None or self._stacked_key is None \
                 or len(self._stacked_key) != len(key) \
                 or not all(a is b for a, b in zip(self._stacked_key, key)):
+            sharding = self.dispatcher.sharding
+
+            def stack(arrs):
+                # one (S, R, ·) array whose shard s IS shard s's view
+                return jax.make_array_from_single_device_arrays(
+                    (len(arrs),) + arrs[0].shape, sharding,
+                    [a[None] for a in arrs])
+
             self._stacked = (
-                jnp.stack([v.betas for v in key]),
-                jnp.stack([v.weights for v in key]),
-                jnp.stack([v.src_quantiles for v in key]),
-                jnp.stack([v.ref_quantiles for v in key]))
+                stack([v.betas for v in key]),
+                stack([v.weights for v in key]),
+                stack([v.src_quantiles for v in key]),
+                stack([v.ref_quantiles for v in key]))
             self._stacked_key = key
         return self._stacked
 
@@ -1063,6 +1091,17 @@ class ShardedTieredBankStore:
         buckets = [np.flatnonzero(shard_ids == s)
                    for s in range(self.num_shards)]
         return local, buckets
+
+    def warm(self, raws: np.ndarray) -> None:
+        """Compile the launch for ``len(raws)``-row per-shard buckets
+        (inert prior-slot rows; no hotness or staging side effects)."""
+        b, k = raws.shape
+        views = [st._view for st in self.shards]
+        self.dispatcher.run_packed(
+            np.zeros((self.num_shards, b, k), np.float32),
+            np.full((self.num_shards, b), self.shards[0]._prior_slot,
+                    np.int32),
+            *self._stacked_views(views))
 
     # -------------------------------------------------------------- serving
     def dispatch(self, expert_scores: np.ndarray, tenant_idx: np.ndarray

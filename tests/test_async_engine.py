@@ -224,9 +224,9 @@ class TestEngineParity:
 
 
 class TestOrderingProperties:
-    """Property-style ordering invariants (hypothesis shim)."""
+    """Property-style ordering invariants (hypothesis)."""
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(st.integers(2, 6), st.integers(5, 40), st.integers(1, 4))
     def test_microbatcher_flushes_map_one_to_one_per_key(
             self, max_batch, n, n_keys):
@@ -252,7 +252,7 @@ class TestOrderingProperties:
         assert flushed == submitted
         assert mb.pending_count == 0
 
-    @settings(max_examples=12)
+    @settings(max_examples=12, deadline=None)
     @given(st.floats(0.5, 50.0), st.floats(0.0, 100.0))
     def test_age_flush_fires_deterministically(self, wait_ms, advance_ms):
         if abs(advance_ms - wait_ms) < 1e-6:
@@ -269,7 +269,7 @@ class TestOrderingProperties:
         else:
             assert expired == [] and mb.pending_count == 1
 
-    @settings(max_examples=5)
+    @settings(max_examples=5, deadline=None)
     @given(st.integers(1, 4), st.integers(6, 20))
     def test_server_batcher_responses_map_one_to_one(self, max_batch, n):
         server = _fleet(3)

@@ -20,7 +20,7 @@ paper's resilience story end-to-end:
     tamper, splice, truncation or generation mismatch is detected.
 
 Fast satellites (default tier-1 lane, also under ``adversarial``): audit
-chain property tests over the hypothesis shim, campaign/world seed-
+chain property tests under hypothesis, campaign/world seed-
 determinism regressions, decision-loop grace/cooldown/instant-block
 semantics, ``ReplicaSet`` stream-floor TTL/LRU eviction, and a small
 serve->decide->audit->replay integration pass.
@@ -123,7 +123,7 @@ def _decision_record(rng_score, threshold, block_threshold, grace, cooldown,
 
 
 # ---------------------------------------------------------------------------
-# Audit chain property tests (hypothesis shim)
+# Audit chain property tests (hypothesis)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.adversarial
@@ -132,7 +132,7 @@ class TestAuditChainProperties:
                     max_size=24),
            st.floats(min_value=0.2, max_value=0.9),
            st.booleans(), st.integers(min_value=0, max_value=3))
-    @settings(max_examples=12)
+    @settings(max_examples=12, deadline=None)
     def test_append_verify_roundtrip(self, scores, threshold, grace, cool):
         log = AuditLog()
         for i, s in enumerate(scores):
@@ -143,7 +143,7 @@ class TestAuditChainProperties:
 
     @given(st.integers(min_value=0, max_value=9),
            st.integers(min_value=0, max_value=10_000))
-    @settings(max_examples=12)
+    @settings(max_examples=12, deadline=None)
     def test_tamper_any_byte_detected(self, entry_idx, byte_pos):
         log = AuditLog()
         for i in range(10):
@@ -159,7 +159,7 @@ class TestAuditChainProperties:
                    for f in v.failures)
 
     @given(st.integers(min_value=1, max_value=8))
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     def test_truncation_detected(self, n_drop):
         log = AuditLog()
         for i in range(9):
@@ -174,7 +174,7 @@ class TestAuditChainProperties:
         assert {f.kind for f in v.failures} == {"truncated", "head_mismatch"}
 
     @given(st.floats(min_value=0.0, max_value=1.0), st.booleans())
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     def test_digest_independent_of_field_order(self, score, grace):
         record = _decision_record(score, 0.5, 0.95, grace, 0)
         shuffled = dict(reversed(list(record.items())))

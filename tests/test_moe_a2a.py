@@ -15,7 +15,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import dataclasses
 import jax, jax.numpy as jnp
 import numpy as np
-from repro import jax_compat
 from repro.models.config import MoEConfig
 from repro.models import moe as moe_mod
 from repro.models.moe_a2a import moe_forward_a2a
@@ -28,10 +27,9 @@ x = jax.random.normal(jax.random.key(1), (2, 8, d), jnp.float32)
 
 ref, aux_ref = moe_mod.moe_forward(params, x, mo)
 
-# AxisType/set_mesh only exist on newer jax; jax_compat degrades to a plain
-# Mesh + physical `with mesh:` context on 0.4.x.
-mesh = jax_compat.make_mesh((2, 2), ("data", "model"))
-with mesh, jax_compat.set_mesh(mesh):
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     got, aux = jax.jit(
         lambda p, xx: moe_forward_a2a(p, xx, mo)
     )(params, x)
@@ -45,7 +43,7 @@ np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
 def loss(p):
     out, aux2 = moe_forward_a2a(p, x, mo)
     return jnp.sum(out**2) + 0.01 * aux2
-with mesh, jax_compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(loss))(params)
 gn = sum(float(jnp.sum(jnp.abs(t))) for t in jax.tree.leaves(g))
 assert np.isfinite(gn) and gn > 0

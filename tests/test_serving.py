@@ -10,7 +10,7 @@ from repro.serving.batching import MicroBatcher
 from repro.serving.rollout import Replica, ReplicaSet, RollingUpdate
 from repro.serving.server import MuseServer, ServerConfig
 from repro.serving.types import ScoringRequest
-from repro.serving.warmup import warm_up
+from repro.serving.warmup import count_compiles, warm_up
 
 DIM = 8
 
@@ -30,11 +30,13 @@ def _qm(n=32):
     return QuantileMap.identity(n)
 
 
-def _basic_server(extra_shadow: bool = False) -> MuseServer:
+def _basic_server(extra_shadow: bool = False,
+                  config: ServerConfig | None = None) -> MuseServer:
     rules = [ScoringRule(Condition(tenants=("bank1",)), "p-bank1"),
              ScoringRule(Condition(), "p-global")]
     shadows = [ShadowRule(Condition(tenants=("bank1",)), ("p-shadow",))] if extra_shadow else []
-    server = MuseServer(RoutingTable(tuple(rules), tuple(shadows), version="v1"))
+    server = MuseServer(RoutingTable(tuple(rules), tuple(shadows), version="v1"),
+                        config)
     factories = {
         "m1": lambda: _linear_model(1),
         "m2": lambda: _linear_model(2),
@@ -280,3 +282,21 @@ class TestRollout:
         t0 = time.perf_counter()
         server.score_batch([_req("bank1", seed=1)])
         assert time.perf_counter() - t0 < 0.5
+
+    @pytest.mark.parametrize("track_device", [False, True])
+    def test_warmed_server_compiles_nothing_on_first_window(self,
+                                                            track_device):
+        # warm-up reaches the models, the banked kernel and (with device
+        # tracking) the fused tracker at every window bucket
+        server = _basic_server(extra_shadow=True, config=ServerConfig(
+            track_quantiles=track_device, track_device=track_device))
+        warm_up(server, DIM, batch_sizes=(2, 3))
+        # model-group windows of 3 (p-bank1, and its shadow) and 2
+        # (p-global): the buckets of the warmed sizes
+        window = [_req(t, seed=i) for i, t in
+                  enumerate(["bank1", "bank2", "bank1", "x", "bank1"])]
+        with count_compiles() as compiles:
+            responses = server.score_batch(window)
+        assert compiles == []
+        assert len(responses) == len(window)
+        assert server.metrics["kernel_dispatches"] == 3  # 2 live + shadow

@@ -10,7 +10,7 @@ WHAT gets served.  The campaign asserts, on 1/2/4/8 host devices:
     dispatch, whatever the assignment;
   * the partition machinery is lossless under arbitrary tenant->shard
     permutations, uneven occupancy, empty shards, and tenants absent from a
-    batch (hypothesis-shim property sweep);
+    batch (hypothesis property sweep);
   * ``refresh_fleet`` publishes land atomically ACROSS shards: a traffic
     thread never observes a torn per-shard mix and the fleet generation
     stays monotone (concurrency case).
@@ -317,7 +317,7 @@ class TestShardedServerParity:
 
 @pytest.mark.sharded
 class TestShardedProperties:
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(st.integers(0, 10_000), st.integers(1, 8), st.integers(1, 31))
     def test_arbitrary_assignment_preserves_scores_bitwise(
             self, seed, shards, t):
@@ -344,7 +344,7 @@ class TestShardedProperties:
             np.asarray(sbank.to_dense().src_quantiles),
             np.asarray(bank.src_quantiles))
 
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     @given(st.integers(0, 10_000), st.integers(2, 8))
     def test_permuted_assignment_equals_default(self, seed, shards):
         """The assignment is representation only: two different layouts of

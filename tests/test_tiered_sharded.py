@@ -188,6 +188,23 @@ class TestComposedResidency:
         assert store.device_bytes == s * store.per_shard_device_bytes
         assert store.host_bytes == _host(rng, 61).nbytes
 
+    @pytest.mark.parametrize("s", SHARD_COUNTS)
+    def test_each_shard_lives_on_its_own_device(self, s):
+        # shard s's tier view (also after cold rows staged into it) and
+        # shard s's rows of a sharded bank sit on the mesh's device s
+        _needs_devices(s)
+        rng = np.random.default_rng(10)
+        host = _host(rng, 29)
+        store = ShardedTieredBankStore(host, s, _cfg())
+        raws = rng.uniform(0.05, 0.95, (40, 4)).astype(np.float32)
+        store.dispatch(raws, rng.integers(0, 29, 40).astype(np.int32))
+        devices = store.dispatcher.devices
+        assert [st.devices for st in store.shards] == [{d} for d in devices]
+        sbank = ShardedTransformBank.from_dense(
+            host.dense_bank(), s, sharding=store.dispatcher.sharding)
+        for shard in sbank.betas.addressable_shards:
+            assert shard.device == devices[shard.index[0].start or 0]
+
     def test_uneven_shards_share_hot_slot_count(self):
         # 5 rows over 4 shards: shard 0 owns 2 rows, shard 3 owns 1 —
         # every shard still gets the same hot-slot count so the per-shard

@@ -787,8 +787,14 @@ class TestTieredServer:
         assert tiered.prefetch_transforms(names, create=False) == 2
         (store,) = tiered.tiered_stores().values()
         assert store.metrics["prefetched_rows"] == 4
-        # the poll path never creates stores for unseen predictor subsets
-        assert tiered.prefetch_transforms(["p0"], create=False) == 0
+        # any subset of the group pages through the group's one store:
+        # p0 was evicted by the last prefetch, so it stages back
+        assert tiered.prefetch_transforms(["p0"], create=False) == 1
+        assert list(tiered.tiered_stores().values()) == [store]
+        # the poll path never creates a store
+        fresh = _tenant_server(4, tiering=_TIER_CFG)
+        assert fresh.prefetch_transforms(["p0"], create=False) == 0
+        assert not fresh.tiered_stores()
         dense = _tenant_server(2)
         assert not dense.prefetch_enabled
 
