@@ -88,7 +88,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Mapping
 
 import jax.numpy as jnp
@@ -100,6 +99,7 @@ from repro.core.quantiles import (
 )
 from repro.core.transforms import QuantileMap
 from repro.serving.drift import realized_alert_rate, transformed_stream_psi
+from repro.serving.spans import span, timed
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,6 +165,9 @@ class RefreshResult:
 
     generation: int                  # server bank generation after the pass
     reports: tuple[CandidateReport, ...]
+    # the pass's steps, each its ``muse.refresh.<step>`` span; scan covers
+    # the estimator pull (a device tracker's drain) and the snapshots
+    scan_seconds: float
     refit_seconds: float
     validate_seconds: float
     publish_seconds: float
@@ -333,73 +336,79 @@ class CalibrationController:
                 return s.recent
             return s.values
 
-        t0 = time.perf_counter()
-        by_pred: dict[str, list[StreamSnapshot]] = {}
-        for (tenant, pred), s in ready.items():
-            by_pred.setdefault(pred, []).append(s)
-        pred_names = sorted(by_pred)
-        levels = np.linspace(0.0, 1.0, p.n_levels)
-        pooled = [np.concatenate([fit_values(s) for s in by_pred[n]])
-                  for n in pred_names]
-        src_tables = batch_sample_quantiles(pooled, levels)   # (R, n_levels)
-        refit_s = time.perf_counter() - t0
+        with timed("muse.refresh.refit") as refit:
+            by_pred: dict[str, list[StreamSnapshot]] = {}
+            for (tenant, pred), s in ready.items():
+                by_pred.setdefault(pred, []).append(s)
+            pred_names = sorted(by_pred)
+            levels = np.linspace(0.0, 1.0, p.n_levels)
+            pooled = [np.concatenate([fit_values(s) for s in by_pred[n]])
+                      for n in pred_names]
+            src_tables = batch_sample_quantiles(pooled, levels)  # (R, n_lv)
 
         # Step 4: per-stream validation of each predictor's candidate.
-        t0 = time.perf_counter()
-        ref = np.interp(levels, np.linspace(0.0, 1.0, len(self.ref_quantiles)),
-                        self.ref_quantiles)
-        updates: dict[str, QuantileMap] = {}
-        reports: list[CandidateReport] = []
-        for row, pred in enumerate(pred_names):
-            src = src_tables[row]
-            ship = True
-            stream_reports: list[CandidateReport] = []
-            for s in by_pred[pred]:
-                reasons, drift, rate = self._validate(
-                    src, ref, fit_values(s),
-                    s.recent if len(s.recent) else None)
-                ok = not reasons
-                ship = ship and ok
-                stream_reports.append(CandidateReport(
-                    s.tenant, pred, s.count,
-                    "refreshed" if ok else "rejected", reasons, drift, rate))
-            # NOT-ready peer streams of this predictor are recalibrated by
-            # the publish too, yet never joined the pool — give them a
-            # support-coverage vote (robust at small n, unlike PSI/rate):
-            # traffic outside the candidate's support must veto the publish
-            for (t2, p2), s in snaps.items():
-                if p2 != pred or (t2, p2) in ready:
-                    continue
-                peer_reasons: list[str] = []
-                if len(s.values) and \
-                        self._support_coverage(src, s.values) < 0.99:
-                    peer_reasons.append("support_coverage")
-                if len(s.recent) and \
-                        self._support_coverage(src, s.recent) < 0.98:
-                    peer_reasons.append("support_coverage_recent")
-                if peer_reasons:
-                    ship = False
-                    not_ready_reports[(t2, p2)] = dataclasses.replace(
-                        not_ready_reports[(t2, p2)],
-                        reasons=("eq5_gate", *peer_reasons))
-            if ship:
-                updates[pred] = QuantileMap(
-                    src_quantiles=jnp.asarray(src, jnp.float32),
-                    ref_quantiles=jnp.asarray(ref, jnp.float32))
-                reports.extend(stream_reports)
-            else:
-                # withhold the whole predictor: publishing a map one of its
-                # tenants rejects would shift that tenant's alert rate.
-                # Streams that passed individually are marked as vetoed so
-                # the report distinguishes "this stream failed" from "a
-                # peer tenant on the shared predictor failed".
-                reports.extend(
-                    r if r.status == "rejected" else dataclasses.replace(
-                        r, status="rejected", reasons=("vetoed_by_peer",))
-                    for r in stream_reports)
-        reports = list(not_ready_reports.values()) + reports
-        validate_s = time.perf_counter() - t0
-        return updates, reports, refit_s, validate_s
+        with timed("muse.refresh.validate") as validate:
+            ref = np.interp(levels,
+                            np.linspace(0.0, 1.0, len(self.ref_quantiles)),
+                            self.ref_quantiles)
+            updates: dict[str, QuantileMap] = {}
+            reports: list[CandidateReport] = []
+            for row, pred in enumerate(pred_names):
+                src = src_tables[row]
+                ship = True
+                stream_reports: list[CandidateReport] = []
+                for s in by_pred[pred]:
+                    reasons, drift, rate = self._validate(
+                        src, ref, fit_values(s),
+                        s.recent if len(s.recent) else None)
+                    ok = not reasons
+                    ship = ship and ok
+                    stream_reports.append(CandidateReport(
+                        s.tenant, pred, s.count,
+                        "refreshed" if ok else "rejected", reasons, drift,
+                        rate))
+                # NOT-ready peer streams of this predictor are recalibrated
+                # by the publish too, yet never joined the pool — give them
+                # a support-coverage vote (robust at small n, unlike
+                # PSI/rate): traffic outside the candidate's support must
+                # veto the publish
+                for (t2, p2), s in snaps.items():
+                    if p2 != pred or (t2, p2) in ready:
+                        continue
+                    peer_reasons: list[str] = []
+                    if len(s.values) and \
+                            self._support_coverage(src, s.values) < 0.99:
+                        peer_reasons.append("support_coverage")
+                    if len(s.recent) and \
+                            self._support_coverage(src, s.recent) < 0.98:
+                        peer_reasons.append("support_coverage_recent")
+                    if peer_reasons:
+                        ship = False
+                        not_ready_reports[(t2, p2)] = dataclasses.replace(
+                            not_ready_reports[(t2, p2)],
+                            reasons=("eq5_gate", *peer_reasons))
+                if ship:
+                    # rounded to float32 on the host: a conversion on the
+                    # device would compile a program in the first pass
+                    # that ships a map, inside the serving window
+                    updates[pred] = QuantileMap(
+                        src_quantiles=jnp.asarray(src.astype(np.float32)),
+                        ref_quantiles=jnp.asarray(ref.astype(np.float32)))
+                    reports.extend(stream_reports)
+                else:
+                    # withhold the whole predictor: publishing a map one of
+                    # its tenants rejects would shift that tenant's alert
+                    # rate.  Streams that passed individually are marked as
+                    # vetoed so the report distinguishes "this stream
+                    # failed" from "a peer tenant on the shared predictor
+                    # failed".
+                    reports.extend(
+                        r if r.status == "rejected" else
+                        dataclasses.replace(r, status="rejected",
+                                            reasons=("vetoed_by_peer",))
+                        for r in stream_reports)
+            reports = list(not_ready_reports.values()) + reports
+        return updates, reports, refit.seconds, validate.seconds
 
     # --------------------------------------------------------------- refresh
     def refresh_fleet(self, only: "set[tuple[str, str]] | None" = None,
@@ -418,29 +427,33 @@ class CalibrationController:
         (if any stream was refreshed) is a single atomic generation bump on
         the server.
         """
-        snaps, failures = self._snapshot(self.scan(), only)
-        updates, reports, refit_s, validate_s = self._plan(snaps)
+        with span("muse.refresh"):
+            with timed("muse.refresh.scan") as scan:
+                snaps, failures = self._snapshot(self.scan(), only)
+            updates, reports, refit_s, validate_s = self._plan(snaps)
 
-        # Step 5: one atomic publish for the entire server.
-        t0 = time.perf_counter()
-        generation = self.server.publish_quantile_maps(updates) \
-            if updates else self.server.bank_generation
-        if updates:
-            # tiered topology: a publish may have just admitted tenants past
-            # the Eq.-5 gate (their first calibrated map landed) — run one
-            # promotion pass so they get real hot/victim slots instead of
-            # paging on their next window.  No-op on non-tiered servers;
-            # under tiered-over-sharded this rebalances every shard's tier
-            # in one lockstep pass (per-shard clocks, one store op).
-            rebalance = getattr(self.server, "rebalance_tiers", None)
-            if rebalance is not None:
-                rebalance()
-        publish_s = time.perf_counter() - t0
+            # Step 5: one atomic publish for the entire server.
+            with timed("muse.refresh.publish") as publish:
+                generation = self.server.publish_quantile_maps(updates) \
+                    if updates else self.server.bank_generation
+                if updates:
+                    # tiered topology: a publish may have just admitted
+                    # tenants past the Eq.-5 gate (their first calibrated
+                    # map landed) — run one promotion pass so they get real
+                    # hot/victim slots instead of paging on their next
+                    # window.  No-op on non-tiered servers; under
+                    # tiered-over-sharded this rebalances every shard's
+                    # tier in one lockstep pass (per-shard clocks, one
+                    # store op).
+                    rebalance = getattr(self.server, "rebalance_tiers", None)
+                    if rebalance is not None:
+                        rebalance()
 
         result = RefreshResult(
             generation=generation, reports=tuple(failures + reports),
-            refit_seconds=refit_s, validate_seconds=validate_s,
-            publish_seconds=publish_s, epoch=epoch)
+            scan_seconds=scan.seconds, refit_seconds=refit_s,
+            validate_seconds=validate_s, publish_seconds=publish.seconds,
+            epoch=epoch)
         self.history.append(result)
         return result
 
@@ -545,22 +558,22 @@ class FleetCalibrationController(CalibrationController):
             dict[tuple[str, str], StreamingQuantileEstimator],
             tuple[ReplicaPullFailure, ...], float]:
         """Steps 1–2: pull every replica's checkpoints, merge per stream."""
-        t0 = time.perf_counter()
-        parts: dict[tuple[str, str], list[tuple[dict, dict]]] = {}
-        failures: list[ReplicaPullFailure] = []
-        for rep in self._iter_replicas():
-            try:
-                snap = rep.server.snapshot_estimator_checkpoints()
-            except Exception as e:  # noqa: BLE001 — structured, not raised
-                failures.append(ReplicaPullFailure(
-                    str(getattr(rep, "replica_id", rep)),
-                    f"{type(e).__name__}: {e}"))
-                continue
-            for key, ckpt in snap.items():
-                parts.setdefault(key, []).append(ckpt)
-        merged = {key: StreamingQuantileEstimator.merge_checkpoints(ps)
-                  for key, ps in parts.items()}
-        return merged, tuple(failures), time.perf_counter() - t0
+        with timed("muse.refresh.merge") as pull:
+            parts: dict[tuple[str, str], list[tuple[dict, dict]]] = {}
+            failures: list[ReplicaPullFailure] = []
+            for rep in self._iter_replicas():
+                try:
+                    snap = rep.server.snapshot_estimator_checkpoints()
+                except Exception as e:  # noqa: BLE001 — structured, not raised
+                    failures.append(ReplicaPullFailure(
+                        str(getattr(rep, "replica_id", rep)),
+                        f"{type(e).__name__}: {e}"))
+                    continue
+                for key, ckpt in snap.items():
+                    parts.setdefault(key, []).append(ckpt)
+            merged = {key: StreamingQuantileEstimator.merge_checkpoints(ps)
+                      for key, ps in parts.items()}
+        return merged, tuple(failures), pull.seconds
 
     def scan(self) -> dict[tuple[str, str], "object"]:
         """Step 1 fleet-wide: the MERGED per-stream estimators."""
@@ -603,22 +616,13 @@ class FleetCalibrationController(CalibrationController):
             return rep.server.bank_generation
         return self._publish_to(rep, dict(self._published), target)
 
-    # --------------------------------------------------------------- refresh
-    def refresh_fleet(self, only: "set[tuple[str, str]] | None" = None,
-                      *, epoch: int = -1) -> FleetRefreshResult:
-        """One fleet pass: pull, merge, gate, refit, validate, broadcast.
-
-        Never raises on per-replica failure: pull failures surface in
-        ``result.pull_failures``, publish failures in ``result.nacked``.
-        The fleet generation advances iff at least one replica acked the
-        fenced broadcast; a fully failed (or updateless) pass leaves it
-        unchanged.
-        """
-        merged, pull_failures, merge_s = self._pull_merged()
-        snaps, failures = self._snapshot(merged, only)
-        updates, reports, refit_s, validate_s = self._plan(snaps)
-
-        t0 = time.perf_counter()
+    def _broadcast(self, updates: dict[str, QuantileMap],
+                   pull_failures: tuple[ReplicaPullFailure, ...],
+                   reports: list[CandidateReport]
+                   ) -> tuple[list[str], list[str]]:
+        """Step 4: the fenced broadcast of ``updates`` to every replica
+        that answered the pull; a failed publish is appended to
+        ``reports``.  Returns (acked, nacked) replica ids."""
         acked: list[str] = []
         nacked: list[str] = []
         if updates:
@@ -659,14 +663,34 @@ class FleetCalibrationController(CalibrationController):
             if acked:
                 self._fleet_generation = target
                 self._published = broadcast
-        publish_s = time.perf_counter() - t0
+        return acked, nacked
+
+    # --------------------------------------------------------------- refresh
+    def refresh_fleet(self, only: "set[tuple[str, str]] | None" = None,
+                      *, epoch: int = -1) -> FleetRefreshResult:
+        """One fleet pass: pull, merge, gate, refit, validate, broadcast.
+
+        Never raises on per-replica failure: pull failures surface in
+        ``result.pull_failures``, publish failures in ``result.nacked``.
+        The fleet generation advances iff at least one replica acked the
+        fenced broadcast; a fully failed (or updateless) pass leaves it
+        unchanged.
+        """
+        with span("muse.refresh"):
+            with timed("muse.refresh.scan") as scan:
+                merged, pull_failures, merge_s = self._pull_merged()
+                snaps, failures = self._snapshot(merged, only)
+            updates, reports, refit_s, validate_s = self._plan(snaps)
+            with timed("muse.refresh.publish") as publish:
+                acked, nacked = self._broadcast(updates, pull_failures,
+                                                reports)
 
         result = FleetRefreshResult(
             generation=self._fleet_generation,
             reports=tuple(failures + reports),
-            refit_seconds=refit_s, validate_seconds=validate_s,
-            publish_seconds=publish_s, epoch=epoch,
-            fleet_generation=self._fleet_generation,
+            scan_seconds=scan.seconds, refit_seconds=refit_s,
+            validate_seconds=validate_s, publish_seconds=publish.seconds,
+            epoch=epoch, fleet_generation=self._fleet_generation,
             acked=tuple(acked), nacked=tuple(nacked),
             pull_failures=pull_failures, merged_streams=len(snaps),
             merge_seconds=merge_s)

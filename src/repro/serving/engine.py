@@ -50,6 +50,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro.serving import spans
 from repro.serving.batching import MicroBatcher
 from repro.serving.types import ScoringRequest, ScoringResponse
 
@@ -64,6 +65,10 @@ class _Window:
     shadow_jobs: list[tuple[list[int], list[str]]]
     futures: list[Future | None]     # None for submit_many (drain-collected)
     routing_version: str
+    built: float                               # left the batcher
+    # the window's ``window_log`` entry (``seq`` first), stamped as the
+    # stages run
+    record: dict
     t0: float = 0.0                            # dispatch start (models stage)
     raws: np.ndarray | None = None
     shadow_raws: list[np.ndarray] = dataclasses.field(default_factory=list)
@@ -129,9 +134,11 @@ class AsyncDispatchEngine:
             1, thread_name_prefix="muse-transforms")
         self._track = ThreadPoolExecutor(1, thread_name_prefix="muse-track")
         # submit-time metadata keyed by request identity (FIFO per object,
-        # so resubmitting the same request object is still well-defined);
-        # the future slot is None for submit_many (drain-collected)
-        self._meta: dict[int, list[tuple[Future | None, Any]]] = {}
+        # so resubmitting the same request object is still well-defined):
+        # (future, resolution, arrival on perf_counter); the future slot is
+        # None for submit_many (drain-collected)
+        self._meta: dict[int, list[tuple[Future | None, Any, float]]] = {}
+        self._seq = itertools.count()
         self._completed: list[ScoringResponse] = []
         self.completed_dropped = 0   # evictions from an un-drained buffer
         # stage failures, newest-last (windows whose futures carry the same
@@ -153,7 +160,18 @@ class AsyncDispatchEngine:
         # otherwise be an invisible calibration-freshness cliff)
         self.tick_errors = 0
         self.track_errors = 0
-        self.window_log: list[dict] = []       # per-window dispatch records
+        # per-window dispatch records, one per window that cleared the
+        # transform stage: ``seq``, ``key``, ``size``, ``latency_ms`` (model
+        # stage start to the kernel's result), ``bank_generation``, and the
+        # stamps that split a request's time before and after that span
+        # (perf_counter ms): ``arrival_wait_ms`` (summed over the window's
+        # requests, arrival to leaving the batcher), ``lane_wait_ms``
+        # (leaving the batcher to the model stage's start),
+        # ``model_fetch_ms`` / ``kernel_wait_ms`` (blocked on the device's
+        # model and kernel results, ``repro.serving.spans``), ``respond_ms``
+        # (the kernel's result to the last future set; written after
+        # delivery)
+        self.window_log: list[dict] = []
         self._epoch = 0
         self._running = False
         self._closed = False
@@ -244,13 +262,14 @@ class AsyncDispatchEngine:
         The future resolves when the request's window clears the transform
         stage (responses never wait on estimator tracking).
         """
+        arrived = time.perf_counter()
         fut: Future = Future()
         with self._lock:
             if self._closed:
                 raise RuntimeError("engine is closed")
             res = self.server.routing.resolve(request.intent)
             key = self.server.group_key(res)
-            self._meta.setdefault(id(request), []).append((fut, res))
+            self._meta.setdefault(id(request), []).append((fut, res, arrived))
             batch = self.batcher.add(key, request) or self._take_ready(key)
             if batch:
                 self._launch_locked(self._build_window(key, batch))
@@ -286,6 +305,7 @@ class AsyncDispatchEngine:
             chunk = list(itertools.islice(it, 64))
             if not chunk:
                 break
+            arrived = time.perf_counter()
             # chunked lock scope: the stages start consuming while the rest
             # of the stream is still being enqueued
             with self._lock:
@@ -296,7 +316,8 @@ class AsyncDispatchEngine:
                 for request in chunk:
                     res = resolve(request.intent)
                     key = group_key(res)
-                    self._meta.setdefault(id(request), []).append((None, res))
+                    self._meta.setdefault(id(request), []).append(
+                        (None, res, arrived))
                     batch = self.batcher.add(key, request) \
                         or self._take_ready(key)
                     if batch:
@@ -312,10 +333,12 @@ class AsyncDispatchEngine:
                 # a tick that fired just before close() finished must not
                 # launch windows into draining/shut-down executors
                 return 0
-            n = 0
-            for key, batch in self.batcher.expired():
-                self._launch_locked(self._build_window(key, batch))
-                n += 1
+            expired = self.batcher.expired()
+            if expired:
+                with spans.span("muse.flush"):
+                    for key, batch in expired:
+                        self._launch_locked(self._build_window(key, batch))
+            n = len(expired)
             if self._prefetchable:
                 # still-accumulating windows: collect their live predictor
                 # names under the lock, prefetch OUTSIDE it (a host->device
@@ -457,13 +480,16 @@ class AsyncDispatchEngine:
     # --------------------------------------------------------------- stages
     def _build_window(self, key: str, batch: list[ScoringRequest]) -> _Window:
         """Assemble a window from a flushed batch (caller holds the lock)."""
+        built = time.perf_counter()
         futures, pred_names = [], []
+        arrival_wait = 0.0
         shadow_groups: dict[tuple[str, ...], tuple[list[int], list[str]]] = {}
         predictors = self.server.predictors
         for i, req in enumerate(batch):
-            fut, res = self._meta[id(req)].pop(0)
+            fut, res, arrived = self._meta[id(req)].pop(0)
             if not self._meta[id(req)]:
                 del self._meta[id(req)]
+            arrival_wait += built - arrived
             futures.append(fut)
             pred_names.append(res.live)
             for s in res.shadows:
@@ -474,7 +500,9 @@ class AsyncDispatchEngine:
         return _Window(
             key=key, requests=batch, pred_names=pred_names,
             shadow_jobs=list(shadow_groups.values()), futures=futures,
-            routing_version=self.server.routing.version)
+            routing_version=self.server.routing.version, built=built,
+            record={"seq": next(self._seq), "key": key, "size": len(batch),
+                    "arrival_wait_ms": arrival_wait * 1e3})
 
     def _note_prefetch_error(self, key: str, exc: BaseException) -> None:
         """Record a non-race prefetch fault: the window still dispatches
@@ -500,30 +528,8 @@ class AsyncDispatchEngine:
     def _model_stage(self, win: _Window) -> None:
         """Stage 1: expert-model execution (live + shadow groups)."""
         try:
-            win.t0 = time.perf_counter()
-            plane = self.server.plane           # per-STAGE snapshot
-            idxs = list(range(len(win.requests)))
-            win.raws = self.server.run_models(
-                win.requests, idxs, win.pred_names, win.raw_cache, plane)
-            for s_idxs, s_names in win.shadow_jobs:
-                win.shadow_raws.append(self.server.run_models(
-                    win.requests, s_idxs, s_names, win.raw_cache, plane))
-            if self._prefetchable:
-                # this window's transform stage is next: stage its cold bank
-                # rows NOW, overlapped with the previous window's kernel
-                # (create=True — the names-tuple is exactly what the
-                # transform stage will dispatch with)
-                try:
-                    self.server.prefetch_transforms(
-                        win.pred_names, plane, create=True)
-                except KeyError:
-                    # expected race: a predictor in this window was
-                    # undeployed after the stage-time plane snapshot —
-                    # the transform stage below resolves against a fresh
-                    # plane and fails (or serves) on its own terms
-                    pass
-                except Exception as e:  # noqa: BLE001 — best-effort warm-up
-                    self._note_prefetch_error(win.key, e)
+            with spans.bind(win.record), spans.span("muse.models"):
+                self._run_models(win)
         except BaseException as e:  # noqa: BLE001 — deliver via futures
             win.error = e
         self._transforms.submit(self._transform_stage, win)
@@ -536,19 +542,61 @@ class AsyncDispatchEngine:
                 if batch:
                     self._launch_locked(self._build_window(win.key, batch))
 
+    def _run_models(self, win: _Window) -> None:
+        """The model stage's work, inside its span."""
+        win.t0 = time.perf_counter()
+        win.record["lane_wait_ms"] = (win.t0 - win.built) * 1e3
+        plane = self.server.plane           # per-STAGE snapshot
+        idxs = list(range(len(win.requests)))
+        win.raws = self.server.run_models(
+            win.requests, idxs, win.pred_names, win.raw_cache, plane)
+        for s_idxs, s_names in win.shadow_jobs:
+            win.shadow_raws.append(self.server.run_models(
+                win.requests, s_idxs, s_names, win.raw_cache, plane))
+        if self._prefetchable:
+            # this window's transform stage is next: stage its cold bank
+            # rows NOW, overlapped with the previous window's kernel
+            # (create=True — the names-tuple is exactly what the
+            # transform stage will dispatch with)
+            try:
+                self.server.prefetch_transforms(
+                    win.pred_names, plane, create=True)
+            except KeyError:
+                # expected race: a predictor in this window was
+                # undeployed after the stage-time plane snapshot —
+                # the transform stage below resolves against a fresh
+                # plane and fails (or serves) on its own terms
+                pass
+            except Exception as e:  # noqa: BLE001 — best-effort warm-up
+                self._note_prefetch_error(win.key, e)
+
     def _transform_stage(self, win: _Window) -> None:
         """Stage 2: banked kernel + response delivery (live + shadows)."""
         if win.error is not None:
             self._fail(win, win.error)
             return
         try:
-            plane = self.server.plane           # fresh per-STAGE snapshot
+            with spans.bind(win.record):
+                bank, tenant_idx = self._transform_and_respond(win)
+            self._track.submit(self._track_stage, win, bank, tenant_idx)
+        except BaseException as e:  # noqa: BLE001 — deliver via futures
+            self._fail(win, e)
+
+    def _transform_and_respond(self, win: _Window) -> tuple[Any, np.ndarray]:
+        """The transform stage's work: the kernel, then the window's
+        answers; returns what its track stage needs."""
+        plane = self.server.plane           # fresh per-STAGE snapshot
+        with spans.span("muse.transforms"):
             scores, bank, tenant_idx = self.server.apply_transforms(
                 win.raws, win.pred_names, plane)
-            latency_ms = (time.perf_counter() - win.t0) * 1000.0
+        result = time.perf_counter()
+        latency_ms = (result - win.t0) * 1000.0
+        record = win.record
+        with spans.span("muse.respond"):
             responses = self.server.build_responses(
                 win.requests, list(range(len(win.requests))), win.pred_names,
-                scores, win.raws, bank, win.routing_version, latency_ms)
+                scores, win.raws, bank, win.routing_version, latency_ms,
+                window=record["seq"])
             for (s_idxs, s_names), s_raws in zip(win.shadow_jobs,
                                                  win.shadow_raws):
                 s_scores, _, _ = self.server.apply_transforms(
@@ -557,6 +605,8 @@ class AsyncDispatchEngine:
                     win.requests, s_idxs, s_names, s_scores, s_raws,
                     win.routing_version)
             self.server.bump_metric("requests", len(win.requests))
+            record["latency_ms"] = latency_ms
+            record["bank_generation"] = bank.generation
             with self._lock:
                 self._completed.extend(responses)
                 # bound an un-drained buffer (a futures-only caller that
@@ -565,24 +615,22 @@ class AsyncDispatchEngine:
                     drop = len(self._completed) - 65536
                     del self._completed[:drop]
                     self.completed_dropped += drop
-                self.window_log.append({
-                    "key": win.key, "size": len(win.requests),
-                    "latency_ms": latency_ms,
-                    "bank_generation": bank.generation})
+                self.window_log.append(record)
                 if len(self.window_log) > 8192:  # bound long-running growth
                     del self.window_log[:4096]
             for fut, resp in zip(win.futures, responses):
                 if fut is not None:
                     fut.set_result(resp)
-            self._track.submit(self._track_stage, win, bank, tenant_idx)
-        except BaseException as e:  # noqa: BLE001 — deliver via futures
-            self._fail(win, e)
+            record["respond_ms"] = (time.perf_counter() - result) * 1e3
+        return bank, tenant_idx
 
     def _track_stage(self, win: _Window, bank, tenant_idx) -> None:
         """Stage 3: estimator-reservoir updates (a stage behind responses)."""
         try:
-            self.server.track(win.requests, list(range(len(win.requests))),
-                              win.pred_names, win.raws, bank, tenant_idx)
+            with spans.span("muse.track"):
+                self.server.track(win.requests,
+                                  list(range(len(win.requests))),
+                                  win.pred_names, win.raws, bank, tenant_idx)
         except BaseException as e:  # noqa: BLE001 — must never kill serving
             # counted + kept in errors: a recurring track fault silently
             # starves calibration of samples (the refresh gate never opens)

@@ -75,6 +75,7 @@ from repro.core.transforms import (
 from repro.kernels import ops
 from repro.kernels.quantile_track import DeviceQuantileTracker
 from repro.serving.shadow import ShadowSink
+from repro.serving.spans import span, timed
 from repro.serving.tiering import (
     HostBankStore,
     ShardedTieredBankStore,
@@ -418,7 +419,7 @@ class MuseServer:
         # re-passes the Eq.-5 gate (applied to stores built later, too)
         self._cold_names: set[str] = set()
         self.metrics: dict[str, float] = {
-            "requests": 0, "shadow_evals": 0, "kernel_dispatches": 0,
+            "requests": 0, "kernel_dispatches": 0,
             "model_group_calls": 0, "model_calls": 0, "bank_generation": 0,
             "shard_dispatches": 0, "tier_dispatches": 0,
             # uniform-block fast-path coverage of the fused banked kernel:
@@ -559,7 +560,7 @@ class MuseServer:
 
         Returns the new bank generation.
         """
-        with self._control_lock:
+        with self._control_lock, span("muse.publish"):
             return self._publish_quantile_maps_locked(updates, generation)
 
     def _publish_quantile_maps_locked(self, updates: Mapping[str, QuantileMap],
@@ -584,6 +585,23 @@ class MuseServer:
             new_predictors[name] = pred.with_updated_pipeline(
                 pred.pipeline.with_quantile_map(qm))
 
+        with span("muse.publish.bank"):
+            new_banks = self._rebuild_banks(plane, updates, new_predictors,
+                                            gen, generation)
+
+        # the publish point: ONE whole-plane swap, never in-place edits
+        self._plane = _ControlPlane(new_predictors, new_banks, gen)
+        self.metrics["bank_generation"] = gen
+        return gen
+
+    def _rebuild_banks(self, plane: _ControlPlane,
+                       updates: Mapping[str, QuantileMap],
+                       new_predictors: dict[str, Predictor], gen: int,
+                       generation: int | None
+                       ) -> dict[tuple[str, ...], _BankEntry]:
+        """The publish's banks at generation ``gen``: refreshed rows
+        scattered into each cached bank (or the bank rebuilt), untouched
+        banks kept or re-stamped for a fenced publish."""
         new_banks: dict[tuple[str, ...], _BankEntry] = {}
         # dict() first: a dispatch stage on another thread may lazily insert
         # a bank-cache entry mid-iteration (the copy itself is GIL-atomic)
@@ -658,11 +676,7 @@ class MuseServer:
                     bank, self.config.tenant_shards,
                     sharding=self._sharded_dispatch.sharding)
             new_banks[key] = _BankEntry(pipelines, bank, sharded)
-
-        # the publish point: ONE whole-plane swap, never in-place edits
-        self._plane = _ControlPlane(new_predictors, new_banks, gen)
-        self.metrics["bank_generation"] = gen
-        return gen
+        return new_banks
 
     # ------------------------------------------------------------------- data
     def _model_dim(self, pred: Predictor) -> int:
@@ -696,11 +710,11 @@ class MuseServer:
     def build_responses(self, requests, idxs: list[int],
                         pred_names: list[str], scores: np.ndarray,
                         raws: np.ndarray, bank: TransformBank,
-                        routing_version: str, latency_ms: float
-                        ) -> list[ScoringResponse]:
+                        routing_version: str, latency_ms: float,
+                        window: int = -1) -> list[ScoringResponse]:
         """Assemble one window's responses (shared by sync + async drivers;
         ``tolist`` conversions are C-speed).  Row ``j`` answers request
-        ``requests[idxs[j]]``."""
+        ``requests[idxs[j]]``; ``window`` is the engine window's ``seq``."""
         score_list = scores.tolist()
         raw_rows = np.atleast_2d(raws).tolist()
         return [
@@ -712,6 +726,7 @@ class MuseServer:
                 latency_ms=latency_ms,
                 raw_scores=tuple(raw_rows[j]),
                 bank_generation=bank.generation,
+                window=window,
             )
             for j, i in enumerate(idxs)
         ]
@@ -731,7 +746,6 @@ class MuseServer:
                 raw_scores=tuple(raw_rows[j]),
                 routing_version=routing_version,
             ))
-            self.bump_metric("shadow_evals")
 
     def _bank_for(self, names: tuple[str, ...],
                   plane: _ControlPlane | None = None) -> _BankEntry:
@@ -844,12 +858,17 @@ class MuseServer:
                 else:
                     rows[j] = hit
         if fresh:
-            feats = self._window_features(requests, idxs, fresh, dim)
-            pad = _shape_bucket(len(fresh)) - len(fresh)
-            if pad:  # bucketed batch shape: no per-length recompiles
-                feats = np.concatenate(
-                    [feats, np.zeros((pad,) + feats.shape[1:], np.float32)])
-            computed = np.asarray(pred0.raw_scores(feats))[:len(fresh)]
+            with span("muse.models.features"):
+                feats = self._window_features(requests, idxs, fresh, dim)
+                pad = _shape_bucket(len(fresh)) - len(fresh)
+                if pad:  # bucketed batch shape: no per-length recompiles
+                    feats = np.concatenate(
+                        [feats, np.zeros((pad,) + feats.shape[1:],
+                                         np.float32)])
+            with span("muse.models.forward"):
+                out = pred0.raw_scores(feats)
+            with timed("muse.models.fetch", "model_fetch_ms"):
+                computed = np.asarray(out)[:len(fresh)]
             with self._metrics_lock:
                 self.metrics["model_group_calls"] += 1
                 self.metrics["model_calls"] += len(group)
@@ -894,15 +913,18 @@ class MuseServer:
         window's provenance stamp.
         """
         plane = self._plane if plane is None else plane
-        bank_names = self.bank_names(pred_names, plane)  # cache key
-        entry = self._bank_for(bank_names, plane)
-        row_of = {n: r for r, n in enumerate(bank_names)}
-        tenant_idx = np.asarray([row_of[n] for n in pred_names], np.int32)
+        with span("muse.transforms.bank"):
+            bank_names = self.bank_names(pred_names, plane)  # cache key
+            entry = self._bank_for(bank_names, plane)
+            row_of = {n: r for r, n in enumerate(bank_names)}
+            tenant_idx = np.asarray([row_of[n] for n in pred_names],
+                                    np.int32)
         if entry.tiered is not None:
             # tiered topology: slot-remapped banked dispatch against the
             # bounded device view; cold rows stage through the victim cache
             # (normally prefetched by the engine before this stage runs)
-            scores, gen = entry.tiered.dispatch(raws, tenant_idx)
+            with span("muse.transforms.kernel"):
+                scores, gen = entry.tiered.dispatch(raws, tenant_idx)
             self.bump_metric("kernel_dispatches")
             self.bump_metric("tier_dispatches")
             if isinstance(entry.tiered, ShardedTieredBankStore):
@@ -914,25 +936,33 @@ class MuseServer:
             # sharded topology: bucket by owning shard, one shard_map launch
             # of the banked kernel per window (the dispatcher pads per
             # shard, so no outer shape-bucket pad is needed here)
-            scores = self._sharded_dispatch(raws, tenant_idx, entry.sharded)
+            with span("muse.transforms.kernel"):
+                scores = self._sharded_dispatch(raws, tenant_idx,
+                                                entry.sharded)
             self.bump_metric("kernel_dispatches")
             self.bump_metric("shard_dispatches")
             return scores, bank, tenant_idx
-        pad = _shape_bucket(b) - b
-        if pad:  # bucketed kernel shape, same reasoning as run_models
-            kraws = np.concatenate(
-                [raws, np.zeros((pad,) + raws.shape[1:], raws.dtype)])
-            # edge-pad the tenant vector so an otherwise-uniform tail block
-            # keeps the kernel's scalar-prefetch fast path (rows sliced off)
-            kidx = np.concatenate(
-                [tenant_idx, np.full(pad, tenant_idx[-1], np.int32)])
-        else:
-            kraws, kidx = raws, tenant_idx
+        with span("muse.transforms.kernel"):
+            pad = _shape_bucket(b) - b
+            if pad:  # bucketed kernel shape, same reasoning as run_models
+                kraws = np.concatenate(
+                    [raws, np.zeros((pad,) + raws.shape[1:], raws.dtype)])
+                # edge-pad the tenant vector so an otherwise-uniform tail
+                # block keeps the kernel's scalar-prefetch fast path (rows
+                # sliced off)
+                kidx = np.concatenate(
+                    [tenant_idx, np.full(pad, tenant_idx[-1], np.int32)])
+            else:
+                kraws, kidx = raws, tenant_idx
+            if self.config.fused_kernel:
+                scores = ops.score_pipeline_banked(
+                    jnp.asarray(kraws, jnp.float32), jnp.asarray(kidx),
+                    bank.betas, bank.weights,
+                    bank.src_quantiles, bank.ref_quantiles)
+            else:
+                scores = bank(jnp.asarray(kraws, jnp.float32),
+                              jnp.asarray(kidx))
         if self.config.fused_kernel:
-            scores = ops.score_pipeline_banked(
-                jnp.asarray(kraws, jnp.float32), jnp.asarray(kidx),
-                bank.betas, bank.weights,
-                bank.src_quantiles, bank.ref_quantiles)
             # serving-side skip-rate accounting: banked_skip_stats mirrors
             # the kernel's own blocking (pow-2 block, edge-padded tail), so
             # feeding it the UNPADDED tenant vector reports exactly the
@@ -941,11 +971,10 @@ class MuseServer:
             with self._metrics_lock:
                 self.metrics["skip_blocks_uniform"] += stats["uniform_blocks"]
                 self.metrics["skip_blocks_total"] += stats["blocks"]
-        else:
-            scores = bank(jnp.asarray(kraws, jnp.float32),
-                          jnp.asarray(kidx))
         self.bump_metric("kernel_dispatches")
-        return np.asarray(scores)[:b], bank, tenant_idx
+        with timed("muse.transforms.fetch", "kernel_wait_ms"):
+            scores = np.asarray(scores)[:b]
+        return scores, bank, tenant_idx
 
     def track(self, requests: list[ScoringRequest], idxs: list[int],
               pred_names: list[str], raws: np.ndarray, bank: TransformBank,

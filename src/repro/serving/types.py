@@ -51,6 +51,9 @@ class ScoringResponse:
     # generation of the TransformBank this response was scored under — the
     # calibration-provenance stamp (every row of a window shares exactly one)
     bank_generation: int = -1
+    # the engine window that served it (its ``window_log`` record's ``seq``;
+    # -1 outside the engine)
+    window: int = -1
 
 
 @dataclasses.dataclass(frozen=True)

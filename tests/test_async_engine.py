@@ -199,7 +199,9 @@ class TestEngineParity:
         server = _fleet(2)
         engine = AsyncDispatchEngine(server, max_batch=16, max_wait_ms=1e9)
         engine.score_batch([_req("t0", i) for i in range(16)])  # warm/compile
-        engine.take_completed()
+        # the barrier: the warm window's track stage compiles too, and must
+        # not overlap (and slow) the first timed window
+        engine.drain()
         engine.window_log.clear()
         futs = [engine.submit(_req(f"t{i % 2}", 100 + i)) for i in range(48)]
         out = engine.drain()
