@@ -34,6 +34,12 @@ Consistency model (the "epoch-safe" part):
   engine's ``epoch`` counter, stamped into the returned ``RefreshResult``.
 * ``poll()`` is self-scheduling: ``start()`` arms a timer that flushes
   aged-out windows and re-arms itself — no external serving loop needed.
+* In adaptive mode (``adaptive_batch_cap``) launching is work-conserving:
+  a key's pending events leave the batcher as one window as soon as its
+  model lane is free and no window of any key waits between its model
+  stage and its transform kernel's dispatch, so the device FIFO keeps
+  kernel *N* ahead of forward *N+1*.  The age flush stays the bound on
+  batcher wait.
 * ``drain()`` is a real barrier: it flushes everything pending, then pushes
   a sentinel through each stage executor in pipeline order, so on return
   every window submitted before the drain has fully cleared all three
@@ -70,6 +76,7 @@ class _Window:
     # stages run
     record: dict
     t0: float = 0.0                            # dispatch start (models stage)
+    kernel_queued: bool = False     # no longer holds adaptive launches back
     raws: np.ndarray | None = None
     shadow_raws: list[np.ndarray] = dataclasses.field(default_factory=list)
     raw_cache: dict = dataclasses.field(default_factory=dict)
@@ -95,15 +102,17 @@ class AsyncDispatchEngine:
                  batcher: MicroBatcher | None = None,
                  adaptive_batch_cap: int | None = None,
                  facade_timeout_s: float = 120.0) -> None:
-        """``adaptive_batch_cap``: enable dynamic window growth.  When the
-        key's model stage is still busy with the previous window, a full
-        ``max_batch`` window is NOT dispatched immediately — arrivals keep
-        accumulating and the next dispatch takes the whole backlog as ONE
-        window (bounded by the cap).  Arrival is decoupled from dispatch —
-        the adaptive batching a synchronous batcher cannot do — so a
-        backlogged pipeline amortizes per-window model/kernel dispatch
-        costs instead of queueing fixed-size windows.  None = fixed-size
-        windows (default).
+        """``adaptive_batch_cap``: enable dynamic window growth.  While the
+        key's model stage is busy with the previous window, arrivals keep
+        accumulating; once the lane is free (and no window waits for its
+        transform kernel's dispatch) the key's pending events launch as ONE
+        window: all of them below ``max_batch``, the backlog quantized to
+        ``max_batch``·2^k (bounded by the cap) above it.  Arrival is
+        decoupled from dispatch — the adaptive batching a synchronous
+        batcher cannot do — so a backlogged pipeline amortizes per-window
+        model/kernel dispatch costs, and a lightly loaded one launches
+        without waiting for the age flush.  None = fixed-size windows
+        (default).
 
         ``facade_timeout_s`` bounds each future wait inside the
         ``score_batch`` facade — a wedged stage surfaces as a loud timeout
@@ -120,6 +129,9 @@ class AsyncDispatchEngine:
         self.batcher = batcher if batcher is not None else MicroBatcher(
             max_batch=self._cap, max_wait_ms=max_wait_ms, clock=clock)
         self._inflight_models: dict[str, int] = {}
+        # windows past their model stage whose transform kernel is not
+        # queued yet; adaptive launches wait while any exists
+        self._awaiting_kernel = 0
         self._poll_interval_s = (
             (poll_interval_ms if poll_interval_ms is not None
              else self.batcher.max_wait_ms / 2.0) / 1000.0)
@@ -166,7 +178,11 @@ class AsyncDispatchEngine:
         # stamps that split a request's time before and after that span
         # (perf_counter ms): ``arrival_wait_ms`` (summed over the window's
         # requests, arrival to leaving the batcher), ``lane_wait_ms``
-        # (leaving the batcher to the model stage's start),
+        # (leaving the batcher to the model stage's start), ``launch``
+        # (what released the window from the batcher: ``idle`` a free lane
+        # at submit, ``release`` a lane freed by a kernel dispatch or a
+        # model stage's end, ``age`` the poll's age flush, ``size`` the
+        # batcher full, ``flush`` a forced flush or drain),
         # ``model_fetch_ms`` / ``kernel_wait_ms`` (blocked on the device's
         # model and kernel results, ``repro.serving.spans``), ``respond_ms``
         # (the kernel's result to the last future set; written after
@@ -270,27 +286,59 @@ class AsyncDispatchEngine:
             res = self.server.routing.resolve(request.intent)
             key = self.server.group_key(res)
             self._meta.setdefault(id(request), []).append((fut, res, arrived))
-            batch = self.batcher.add(key, request) or self._take_ready(key)
-            if batch:
-                self._launch_locked(self._build_window(key, batch))
+            self._intake_locked(key, request)
         return fut
 
+    def _intake_locked(self, key: str, request: ScoringRequest) -> None:
+        """Add one request to the batcher and launch what that releases
+        (caller holds the lock)."""
+        batch = self.batcher.add(key, request)
+        if batch:
+            self._launch_locked(self._build_window(key, batch, "size"))
+            return
+        batch = self._take_ready(key)
+        if batch:
+            self._launch_locked(self._build_window(key, batch, "idle"))
+
     def _take_ready(self, key: str) -> list[ScoringRequest]:
-        """Adaptive dispatch decision (caller holds the lock): flush once
-        the base window size is reached AND the key's model stage is idle;
-        while it is busy, keep accumulating (the batcher caps the growth).
-        Window sizes are quantized to base·2^k ≤ cap so the serving shapes
-        stay bounded (one XLA specialization per growth step, not one per
-        arbitrary backlog length)."""
-        if not self._adaptive or self._inflight_models.get(key):
+        """Adaptive dispatch decision (caller holds the lock): take the
+        key's pending events once its model lane is idle and no window of
+        any key waits for its transform kernel's dispatch (so the device
+        runs kernel N before forward N+1); while either holds, keep
+        accumulating (the batcher caps the growth).  Below the base size
+        everything pending goes (the model stage pads to a power-of-two
+        bucket); above it the backlog is quantized to base·2^k ≤ cap so
+        the serving shapes stay bounded."""
+        if (not self._adaptive or self._inflight_models.get(key)
+                or self._awaiting_kernel):
             return []
         n = self.batcher.pending_for(key)
         if n < self._base_batch:
-            return []
+            return self.batcher.take(key)
         size = self._base_batch
         while size * 2 <= min(n, self._cap):
             size *= 2
         return self.batcher.take(key, size)
+
+    def _launch_released_locked(self) -> None:
+        """A model lane or the kernel condition just freed: launch every
+        key the adaptive rule now releases (caller holds the lock)."""
+        if self._closed or not self._adaptive:
+            return
+        for key in self.batcher.pending_keys():
+            batch = self._take_ready(key)
+            if batch:
+                self._launch_locked(self._build_window(key, batch, "release"))
+
+    def _kernel_queued(self, win: _Window) -> None:
+        """``win``'s transform kernel is queued on the device (or the window
+        will dispatch none): it no longer holds back adaptive launches."""
+        with self._lock:
+            if win.kernel_queued:
+                return
+            win.kernel_queued = True
+            self._awaiting_kernel -= 1
+            self._launch_released_locked()
 
     def submit_many(self, requests: list[ScoringRequest]) -> None:
         """Bulk ingestion: enqueue a request stream without per-request
@@ -318,10 +366,7 @@ class AsyncDispatchEngine:
                     key = group_key(res)
                     self._meta.setdefault(id(request), []).append(
                         (None, res, arrived))
-                    batch = self.batcher.add(key, request) \
-                        or self._take_ready(key)
-                    if batch:
-                        self._launch_locked(self._build_window(key, batch))
+                    self._intake_locked(key, request)
 
     def poll(self) -> int:
         """Flush aged-out windows into the pipeline; returns windows launched.
@@ -337,7 +382,8 @@ class AsyncDispatchEngine:
             if expired:
                 with spans.span("muse.flush"):
                     for key, batch in expired:
-                        self._launch_locked(self._build_window(key, batch))
+                        self._launch_locked(
+                            self._build_window(key, batch, "age"))
             n = len(expired)
             if self._prefetchable:
                 # still-accumulating windows: collect their live predictor
@@ -371,7 +417,7 @@ class AsyncDispatchEngine:
         with self._lock:
             n = 0
             for key, batch in self.batcher.flush_all():
-                self._launch_locked(self._build_window(key, batch))
+                self._launch_locked(self._build_window(key, batch, "flush"))
                 n += 1
         return n
 
@@ -478,8 +524,10 @@ class AsyncDispatchEngine:
         return fut
 
     # --------------------------------------------------------------- stages
-    def _build_window(self, key: str, batch: list[ScoringRequest]) -> _Window:
-        """Assemble a window from a flushed batch (caller holds the lock)."""
+    def _build_window(self, key: str, batch: list[ScoringRequest],
+                      launch: str) -> _Window:
+        """Assemble a window from a flushed batch (caller holds the lock);
+        ``launch`` names what released it (``window_log``)."""
         built = time.perf_counter()
         futures, pred_names = [], []
         arrival_wait = 0.0
@@ -502,7 +550,7 @@ class AsyncDispatchEngine:
             shadow_jobs=list(shadow_groups.values()), futures=futures,
             routing_version=self.server.routing.version, built=built,
             record={"seq": next(self._seq), "key": key, "size": len(batch),
-                    "arrival_wait_ms": arrival_wait * 1e3})
+                    "launch": launch, "arrival_wait_ms": arrival_wait * 1e3})
 
     def _note_prefetch_error(self, key: str, exc: BaseException) -> None:
         """Record a non-race prefetch fault: the window still dispatches
@@ -532,15 +580,18 @@ class AsyncDispatchEngine:
                 self._run_models(win)
         except BaseException as e:  # noqa: BLE001 — deliver via futures
             win.error = e
-        self._transforms.submit(self._transform_stage, win)
-        # adaptive backlog pickup: the model lane is free again — take the
-        # (quantized) backlog accumulated for this key as ONE window
         with self._lock:
+            # the lane is free again; a window launched onto it now still
+            # runs after this call returns (one worker per lane), so its
+            # transform stage stays behind this one's
             self._inflight_models[win.key] -= 1
-            if not self._closed:
-                batch = self._take_ready(win.key)
-                if batch:
-                    self._launch_locked(self._build_window(win.key, batch))
+            if win.error is None:
+                self._awaiting_kernel += 1
+            else:
+                win.kernel_queued = True      # it will dispatch no kernel
+                # adaptive backlog pickup: nothing waits for a kernel
+                self._launch_released_locked()
+        self._transforms.submit(self._transform_stage, win)
 
     def _run_models(self, win: _Window) -> None:
         """The model stage's work, inside its span."""
@@ -576,11 +627,18 @@ class AsyncDispatchEngine:
             self._fail(win, win.error)
             return
         try:
-            with spans.bind(win.record):
+            # the server's ``spans.dispatched()`` calls back once the kernel
+            # is queued, so the next forward lands behind it on the device
+            with spans.bind(win.record,
+                            on_dispatch=lambda: self._kernel_queued(win)):
                 bank, tenant_idx = self._transform_and_respond(win)
             self._track.submit(self._track_stage, win, bank, tenant_idx)
         except BaseException as e:  # noqa: BLE001 — deliver via futures
             self._fail(win, e)
+        finally:
+            # a server that never called back, or a stage that failed
+            # before its kernel, must not hold launches back
+            self._kernel_queued(win)
 
     def _transform_and_respond(self, win: _Window) -> tuple[Any, np.ndarray]:
         """The transform stage's work: the kernel, then the window's

@@ -75,7 +75,7 @@ from repro.core.transforms import (
 from repro.kernels import ops
 from repro.kernels.quantile_track import DeviceQuantileTracker
 from repro.serving.shadow import ShadowSink
-from repro.serving.spans import span, timed
+from repro.serving.spans import dispatched, span, timed
 from repro.serving.tiering import (
     HostBankStore,
     ShardedTieredBankStore,
@@ -910,7 +910,9 @@ class MuseServer:
         here wholesale (raw expert scores are generation-independent), and
         every row of the window scores under exactly one bank generation.
         Returns (scores, bank, tenant_idx); the bank's ``generation`` is the
-        window's provenance stamp.
+        window's provenance stamp.  On every topology, once the kernel call
+        returns and before the result is fetched, ``spans.dispatched()``
+        makes the ``on_dispatch`` call the engine bound to the window.
         """
         plane = self._plane if plane is None else plane
         with span("muse.transforms.bank"):
@@ -925,6 +927,7 @@ class MuseServer:
             # (normally prefetched by the engine before this stage runs)
             with span("muse.transforms.kernel"):
                 scores, gen = entry.tiered.dispatch(raws, tenant_idx)
+            dispatched()
             self.bump_metric("kernel_dispatches")
             self.bump_metric("tier_dispatches")
             if isinstance(entry.tiered, ShardedTieredBankStore):
@@ -939,6 +942,7 @@ class MuseServer:
             with span("muse.transforms.kernel"):
                 scores = self._sharded_dispatch(raws, tenant_idx,
                                                 entry.sharded)
+            dispatched()
             self.bump_metric("kernel_dispatches")
             self.bump_metric("shard_dispatches")
             return scores, bank, tenant_idx
@@ -962,6 +966,9 @@ class MuseServer:
             else:
                 scores = bank(jnp.asarray(kraws, jnp.float32),
                               jnp.asarray(kidx))
+        # the kernel is queued: the engine may launch the next window's
+        # forward behind it, before this stage blocks on the result
+        dispatched()
         if self.config.fused_kernel:
             # serving-side skip-rate accounting: banked_skip_stats mirrors
             # the kernel's own blocking (pow-2 block, edge-padded tail), so

@@ -11,7 +11,9 @@ none running it is a shared no-op, a fraction of a microsecond.
 are added to that field of the window record the current thread has
 bound (``bind``).  The engine binds each window's ``window_log`` record
 around the window's stages, so the server's stage code stamps the window
-it serves without taking it as a parameter.
+it serves without taking it as a parameter.  The same binding carries the
+engine's ``on_dispatch`` call, which the stage code makes through
+``dispatched()`` once the window's kernel is queued on the device.
 
 Names stay bare (``muse.models.fetch``), with no per-call argument: the
 trace is reduced by event name.
@@ -20,6 +22,7 @@ from __future__ import annotations
 
 import threading
 from time import perf_counter
+from typing import Callable
 
 from jax.profiler import TraceAnnotation
 
@@ -71,17 +74,31 @@ class timed:
 
 
 class bind:
-    """Make ``record`` the current thread's window record in the block."""
+    """Make ``record`` the current thread's window record in the block,
+    and ``on_dispatch`` the call that ``dispatched`` makes."""
 
-    __slots__ = ("record", "_previous")
+    __slots__ = ("record", "on_dispatch", "_previous")
 
-    def __init__(self, record: dict) -> None:
+    def __init__(self, record: dict,
+                 on_dispatch: Callable[[], None] | None = None) -> None:
         self.record = record
+        self.on_dispatch = on_dispatch
 
     def __enter__(self) -> dict:
-        self._previous = getattr(_local, "record", None)
-        _local.record = self.record
+        self._previous = (getattr(_local, "record", None),
+                          getattr(_local, "on_dispatch", None))
+        _local.record, _local.on_dispatch = self.record, self.on_dispatch
         return self.record
 
     def __exit__(self, *exc) -> None:
-        _local.record = self._previous
+        _local.record, _local.on_dispatch = self._previous
+
+
+def dispatched() -> None:
+    """The current thread's window has its kernel queued on the device:
+    make the bound ``on_dispatch`` call, once per binding (the shadow
+    dispatches that follow in the same stage make none)."""
+    fn = getattr(_local, "on_dispatch", None)
+    if fn is not None:
+        _local.on_dispatch = None
+        fn()

@@ -14,6 +14,7 @@ pass via ``./test.sh --concurrency``); the end-to-end soak is additionally
 """
 import threading
 import time
+from types import SimpleNamespace
 
 import jax.numpy as jnp
 import numpy as np
@@ -37,7 +38,8 @@ from repro.serving import (
     ServerBatcher,
     ServerConfig,
 )
-from repro.serving.types import ScoringRequest
+from repro.serving import spans
+from repro.serving.types import ScoringRequest, ScoringResponse
 
 DIM = 8
 TOL = 1e-5
@@ -632,3 +634,239 @@ class TestPollTimerShutdown:
         del eng.batcher.expired              # restore for a clean close
         eng.close()
         assert eng.tick_errors >= 3
+
+
+class _GatedServer:
+    """Duck-typed server whose model stage, transform kernel and kernel
+    result fetch each wait on a gate of their own, so a test can hold a
+    window at any of them and watch what the engine launches meanwhile.
+    A request's tenant is its model-group key.  ``log`` keeps, in order,
+    each model stage's start (``("model", key, size)``) and each kernel
+    dispatch (``("kernel", key, size)``)."""
+
+    def __init__(self, *, hold_models=False, hold_kernels=False,
+                 hold_fetches=False, fail_kernels=False):
+        self.routing = SimpleNamespace(
+            version="v1", resolve=lambda intent: SimpleNamespace(
+                live=f"p-{intent.tenant}", shadows=()))
+        self.predictors, self.plane = {}, None
+        self.hold = {"model": hold_models, "kernel": hold_kernels,
+                     "fetch": hold_fetches}
+        self.fail_kernels = fail_kernels
+        self.gates = {"model": [], "kernel": [], "fetch": []}
+        self.log = []
+        self._lock = threading.Lock()
+
+    @staticmethod
+    def group_key(res):
+        return res.live[2:]
+
+    def _pass(self, stage, entry=None):
+        gate = threading.Event()
+        with self._lock:
+            if not self.hold[stage]:
+                gate.set()
+            self.gates[stage].append(gate)
+            if entry is not None:
+                self.log.append(entry)
+        assert gate.wait(10.0), f"{stage} gate never opened"
+
+    def open(self, stage):
+        """Open every gate of ``stage``, and the later ones too."""
+        with self._lock:
+            self.hold[stage] = False
+            gates = list(self.gates[stage])
+        for gate in gates:
+            gate.set()
+
+    def calls(self, stage):
+        with self._lock:
+            return len(self.gates[stage])
+
+    def run_models(self, requests, idxs, pred_names, raw_cache=None,
+                   plane=None):
+        self._pass("model", ("model", pred_names[0][2:], len(idxs)))
+        return np.zeros((len(idxs), 1), np.float32)
+
+    def apply_transforms(self, raws, pred_names, plane=None):
+        self._pass("kernel")
+        if self.fail_kernels:
+            raise RuntimeError("kernel refused")
+        with self._lock:
+            self.log.append(("kernel", pred_names[0][2:], len(pred_names)))
+        spans.dispatched()
+        self._pass("fetch")
+        bank = SimpleNamespace(generation=0)
+        return (np.zeros(len(pred_names), np.float32), bank,
+                np.zeros(len(pred_names), np.int32))
+
+    def build_responses(self, requests, idxs, pred_names, scores, raws, bank,
+                        routing_version, latency_ms, window=-1):
+        return [ScoringResponse(r.request_id, 0.0, pred_names[i],
+                                routing_version, latency_ms, window=window)
+                for i, r in zip(idxs, requests)]
+
+    def bump_metric(self, name, n=1):
+        pass
+
+    def track(self, *args):
+        pass
+
+
+def _until(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "condition never held"
+        time.sleep(0.001)
+
+
+def _launches(engine):
+    return [(w["key"], w["size"], w["launch"]) for w in engine.window_log]
+
+
+class TestAdaptiveLaunch:
+    """The adaptive engine launches a key's pending events as one window
+    when its model lane is free and no window waits for its transform
+    kernel's dispatch; the age flush and the cap still bound the wait.
+    Stages are held on gates and the batcher's clock is injected, so no
+    window ever ages unless a test advances the clock."""
+
+    @staticmethod
+    def _engine(server, t, **kw):
+        kw.setdefault("adaptive_batch_cap", 16)
+        return AsyncDispatchEngine(server, max_batch=4, max_wait_ms=10.0,
+                                   clock=lambda: t[0], **kw)
+
+    def test_an_event_on_an_idle_lane_launches_at_once(self):
+        server, t = _GatedServer(), [0.0]
+        engine = self._engine(server, t)
+        fut = engine.submit(_req("a", 0))
+        # no poll, no clock advance: the free lane took the one event
+        assert fut.result(timeout=10.0).window == 0
+        engine.close()
+        assert _launches(engine) == [("a", 1, "idle")]
+
+    def test_events_behind_a_busy_lane_launch_as_one_window_at_release(self):
+        server, t = _GatedServer(hold_models=True), [0.0]
+        engine = self._engine(server, t)
+        engine.submit(_req("a", 0))
+        _until(lambda: server.calls("model") == 1)
+        futs = [engine.submit(_req("a", i)) for i in range(1, 4)]
+        assert engine.pending_count == 3      # the lane is busy: they wait
+        server.open("model")
+        for fut in futs:
+            assert fut.result(timeout=10.0).window == 1
+        engine.close()
+        assert _launches(engine) == [("a", 1, "idle"), ("a", 3, "release")]
+
+    def test_the_next_forward_waits_for_the_previous_kernel_dispatch(self):
+        server, t = _GatedServer(hold_kernels=True), [0.0]
+        engine = self._engine(server, t)
+        engine.submit(_req("a", 0))
+        # window 0 left its model stage and waits for its kernel
+        _until(lambda: server.calls("kernel") == 1)
+        futs = [engine.submit(_req("a", 1)), engine.submit(_req("b", 2))]
+        time.sleep(0.05)
+        # both lanes are free, yet neither key launches behind an
+        # undispatched kernel
+        assert server.calls("model") == 1
+        assert engine.pending_count == 2
+        server.open("kernel")
+        for fut in futs:
+            fut.result(timeout=10.0)
+        engine.close()
+        assert server.log.index(("kernel", "a", 1)) < \
+            min(server.log.index(("model", "a", 1), 1),
+                server.log.index(("model", "b", 1)))
+        assert sorted(_launches(engine)) == [
+            ("a", 1, "idle"), ("a", 1, "release"), ("b", 1, "release")]
+
+    def test_the_next_forward_does_not_wait_for_the_kernel_result(self):
+        server, t = _GatedServer(hold_fetches=True), [0.0]
+        engine = self._engine(server, t)
+        engine.submit(_req("a", 0))
+        _until(lambda: server.calls("fetch") == 1)
+        # window 0's kernel is queued and its result not fetched: the
+        # next window's model stage starts behind it
+        fut = engine.submit(_req("a", 1))
+        _until(lambda: server.calls("model") == 2)
+        assert not fut.done()
+        server.open("fetch")
+        assert fut.result(timeout=10.0).window == 1
+        engine.close()
+        assert _launches(engine) == [("a", 1, "idle"), ("a", 1, "idle")]
+
+    def test_the_age_flush_still_bounds_the_wait_when_the_lane_is_held(self):
+        server, t = _GatedServer(hold_models=True), [0.0]
+        engine = self._engine(server, t)
+        engine.submit(_req("a", 0))
+        _until(lambda: server.calls("model") == 1)
+        t[0] = 0.001
+        futs = [engine.submit(_req("a", i)) for i in (1, 2)]
+        t[0] = 0.009
+        assert engine.poll() == 0             # 8 ms old: not yet
+        t[0] = 0.0115
+        assert engine.poll() == 1             # 10.5 ms old: flushed by age
+        assert engine.pending_count == 0
+        server.open("model")
+        for fut in futs:
+            assert fut.result(timeout=10.0).window == 1
+        engine.close()
+        assert _launches(engine) == [("a", 1, "idle"), ("a", 2, "age")]
+
+    def test_the_cap_still_flushes_by_size_when_the_lane_is_held(self):
+        server, t = _GatedServer(hold_models=True), [0.0]
+        engine = self._engine(server, t, adaptive_batch_cap=8)
+        engine.submit(_req("a", 0))
+        _until(lambda: server.calls("model") == 1)
+        for i in range(1, 9):
+            engine.submit(_req("a", i))
+        assert engine.pending_count == 0      # the batcher filled at the cap
+        server.open("model")
+        engine.close()
+        assert _launches(engine) == [("a", 1, "idle"), ("a", 8, "size")]
+
+    def test_a_failed_transform_stage_does_not_hold_launches_back(self):
+        server, t = _GatedServer(hold_models=True, fail_kernels=True), [0.0]
+        engine = self._engine(server, t)
+        first = engine.submit(_req("a", 0))
+        _until(lambda: server.calls("model") == 1)
+        second = engine.submit(_req("a", 1))
+        server.open("model")
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            first.result(timeout=10.0)
+        # the failed window dispatched no kernel, and released the lane
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            second.result(timeout=10.0)
+        engine.close()
+        assert server.calls("model") == 2
+        assert engine.pending_count == 0
+
+    @pytest.mark.parametrize("hold_models", [False, True])
+    def test_a_non_adaptive_engine_forms_the_same_windows(self, hold_models):
+        """Fixed-size windows, flushed by size or age and never by a free
+        lane: the windows a plain ``MicroBatcher`` forms from the same
+        arrivals on the same clock, whatever the lanes are doing."""
+        server, t = _GatedServer(hold_models=hold_models), [0.0]
+        engine = self._engine(server, t, adaptive_batch_cap=None)
+        batcher = MicroBatcher(max_batch=4, max_wait_ms=10.0,
+                               clock=lambda: t[0])
+        want = []
+        for i in range(11):
+            t[0] = 0.002 * i
+            req = _req("ab"[i % 3 == 2], i)
+            engine.submit(req)
+            batch = batcher.add(req.intent.tenant, req)
+            if batch:
+                want.append((req.intent.tenant, len(batch), "size"))
+            if i % 4 == 3:
+                engine.poll()
+                want += [(k, len(b), "age") for k, b in batcher.expired()]
+        t[0] = 1.0
+        engine.poll()
+        want += [(k, len(b), "age") for k, b in batcher.expired()]
+        server.open("model")
+        engine.drain()
+        engine.close()
+        assert sorted(_launches(engine)) == sorted(want)
+        assert {launch for _, _, launch in want} == {"size", "age"}

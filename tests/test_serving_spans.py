@@ -29,6 +29,8 @@ from repro.serving import (
     RefreshPolicy,
     ServerConfig,
 )
+from repro.serving import spans
+from repro.serving.tiering import TieringConfig
 from repro.serving.types import ScoringRequest
 from repro.serving.warmup import count_compiles
 
@@ -50,13 +52,13 @@ def _model(seed: int):
 FACTORIES = {"m1": lambda: _model(1), "m2": lambda: _model(2)}
 
 
-def _server(n_tenants: int = 2, n_levels: int = 64) -> MuseServer:
+def _server(n_tenants: int = 2, n_levels: int = 64, **config) -> MuseServer:
     rules = tuple(ScoringRule(Condition(tenants=(f"t{i}",)), f"p{i}")
                   for i in range(n_tenants)) + \
         (ScoringRule(Condition(), "p0"),)
     server = MuseServer(RoutingTable(rules, (), version="v1"),
                         ServerConfig(refresh_alert_rate=0.05,
-                                     refresh_rel_error=0.5))
+                                     refresh_rel_error=0.5, **config))
     for i in range(n_tenants):
         server.deploy(PredictorSpec(f"p{i}", ("m1", "m2"), (0.2, 0.4),
                                     (1.0, 1.0),
@@ -148,6 +150,30 @@ class TestWindowStamps:
     def test_outside_the_engine_a_response_has_no_window(self):
         resp = _server().score_batch([_req("t0", 1)])[0]
         assert resp.window == -1
+
+
+@pytest.mark.parametrize("config", [
+    {}, {"tenant_shards": 1},
+    {"tiering": TieringConfig(hot_capacity=1, victim_capacity=2)}],
+    ids=["dense", "sharded", "tiered"])
+def test_apply_transforms_calls_back_once_its_kernel_is_queued(config):
+    """On every topology the bound ``on_dispatch`` runs once per binding,
+    after the kernel call and before the result's fetch is stamped; the
+    shadow dispatches that follow in the same stage, and calls outside any
+    binding, make none."""
+    server = _server(2, **config)
+    reqs = [_req(f"t{i % 2}", i) for i in range(6)]
+    names = [f"p{i % 2}" for i in range(6)]
+    raws = server.run_models(reqs, list(range(6)), names)
+    record, calls = {}, []
+    with spans.bind(record,
+                    on_dispatch=lambda: calls.append(dict(record))):
+        scores, _, _ = server.apply_transforms(raws, names)
+        server.apply_transforms(raws, names)
+    server.apply_transforms(raws, names)
+    assert len(calls) == 1
+    assert "kernel_wait_ms" not in calls[0]     # not fetched yet
+    assert len(scores) == 6
 
 
 def _run(windows):
