@@ -84,3 +84,27 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array,
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgs,bshd->bhgd", probs, v_cache.astype(jnp.float32))
     return out.reshape(b, hq, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# selective scan (Mamba-1) — a sequential lax.scan over time
+# ---------------------------------------------------------------------------
+
+def selective_scan(dt: Array, x: Array, b: Array, c: Array, a: Array,
+                   d: Array, h0: Array) -> tuple[Array, Array]:
+    """h_t = exp(dt_t a) h_{t-1} + (dt_t x_t) b_t^T, y_t = h_t c_t + d x_t,
+    in float32.  dt, x: (B, T, C); b, c: (B, T, N); a: (C, N); d: (C,);
+    h0: (B, C, N).  Returns (y (B, T, C), final state (B, C, N))."""
+    f32 = jnp.float32
+    a, d = a.astype(f32), d.astype(f32)
+
+    def step(h, inp):
+        dt_t, x_t, b_t, c_t = inp                        # (B, C), (B, N)
+        h = jnp.exp(dt_t[..., None] * a) * h \
+            + (dt_t * x_t)[..., None] * b_t[:, None, :]
+        return h, jnp.einsum("bcn,bn->bc", h, c_t,
+                             precision=jax.lax.Precision.HIGHEST) + d * x_t
+
+    seq = [jnp.moveaxis(v.astype(f32), 1, 0) for v in (dt, x, b, c)]
+    h, ys = jax.lax.scan(step, h0.astype(f32), seq)
+    return jnp.moveaxis(ys, 0, 1), h

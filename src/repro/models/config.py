@@ -51,6 +51,9 @@ class MambaConfig:
     d_conv: int = 4
     expand: int = 2          # d_inner = expand * d_model
     dt_rank: int = 0         # 0 -> ceil(d_model / 16)
+    # Jamba's mixer: RMSNorm (the model's norm_eps) on dt (width dt_rank),
+    # B and C (width d_state) before the discretisation (arXiv:2403.19887 §3)
+    inner_norms: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +79,7 @@ class ModelConfig:
     layer_pattern: tuple[BlockSpec, ...] = (BlockSpec(),)
     # attention
     rope_theta: float = 10000.0
+    rope: bool = True                 # False: no positional encoding (Jamba)
     qk_norm: bool = False             # qwen3-style per-head q/k RMSNorm
     mrope: bool = False               # qwen2-vl multimodal RoPE (t/h/w sections)
     mrope_sections: tuple[int, int, int] = (16, 24, 24)  # in rotary half-dims
@@ -145,6 +149,8 @@ class ModelConfig:
                 total += dt_rank * d_in          # dt_proj
                 total += d_in * mc.d_state       # A_log
                 total += d_in                    # D
+                if mc.inner_norms:
+                    total += dt_rank + 2 * mc.d_state
                 total += d_in * d                # out_proj
                 total += d                       # norm
             elif spec.mixer == "mlstm":

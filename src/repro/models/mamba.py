@@ -1,13 +1,11 @@
-"""Mamba (S6) selective state-space mixer — TPU-adapted chunked scan.
+"""Mamba (S6) selective state-space mixer.
 
-Hardware adaptation (DESIGN.md §2): the CUDA reference fuses the selective
-scan so the (d_inner × d_state) per-timestep states never hit HBM.  The TPU-
-native equivalent here is a **chunked two-level scan**: a sequential
-`lax.scan` over chunks carries the (B, d_inner, N) state, and within each
-chunk a `lax.associative_scan` (log-depth) materializes only
-(B, Q, d_inner, N) — bounded VMEM-scale working set per chunk instead of the
-O(T · d_inner · N) tensor a naive associative scan over the full sequence
-would allocate.  Semantics are exactly Mamba-1 (diagonal A, per-channel dt).
+The full-sequence forward runs the recurrence in the Pallas selective-scan
+kernel (``kernels/selective_scan.py``), which keeps each (d_inner, N) state
+slice in VMEM and reads only dt, x, B and C: no (B, T, d_inner, N) tensor
+reaches HBM.  Semantics are exactly Mamba-1 (diagonal A, per-channel dt);
+with ``MambaConfig.inner_norms`` dt, B and C pass an RMSNorm first, as in
+Jamba's mixer (arXiv:2403.19887 §3).
 
 Decode is the O(1) recurrent step with a (B, d_conv-1, d_inner) conv tail and
 a (B, d_inner, N) SSM state — constant memory at 500k+ context.
@@ -20,6 +18,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.selective_scan import selective_scan
 from repro.models import layers
 from repro.models.config import MambaConfig
 
@@ -48,6 +47,11 @@ def init_mamba(key, d_model: int, mc: MambaConfig, dtype=jnp.float32) -> PyTree:
         + math.log(0.001)
     )
     dt_bias = jnp.log(jnp.expm1(dt_init))  # inverse softplus
+    norms = {}
+    if mc.inner_norms:
+        norms = {"dt_norm": layers.init_rmsnorm(rank, dtype),
+                 "b_norm": layers.init_rmsnorm(mc.d_state, dtype),
+                 "c_norm": layers.init_rmsnorm(mc.d_state, dtype)}
     return {
         "in_proj": layers.init_linear(keys[0], d_model, 2 * d_in, dtype=dtype),
         "conv_w": jax.random.normal(keys[1], (mc.d_conv, d_in), dtype) * 0.1,
@@ -59,6 +63,7 @@ def init_mamba(key, d_model: int, mc: MambaConfig, dtype=jnp.float32) -> PyTree:
         "A_log": jnp.log(a).astype(dtype),
         "D": jnp.ones((d_in,), dtype),
         "out_proj": layers.init_linear(keys[5], d_in, d_model, dtype=dtype),
+        **norms,
     }
 
 
@@ -80,21 +85,26 @@ def _causal_conv(x: Array, w: Array, b: Array, tail: Array | None = None) -> Arr
     return out + b.astype(x.dtype)
 
 
-def _ssm_chunk(abar: Array, bx: Array, h0: Array) -> tuple[Array, Array]:
-    """Within-chunk diagonal SSM via associative scan.
+def _ssm_inputs(params: PyTree, x_conv: Array, mc: MambaConfig, rank: int,
+                eps: float) -> tuple[Array, Array, Array]:
+    """dt (float32, after softplus), B and C from the conv output."""
+    n = mc.d_state
+    proj = layers.linear(params["x_proj"], x_conv)
+    dt_raw, b_mat, c_mat = jnp.split(proj, [rank, rank + n], axis=-1)
+    if mc.inner_norms:
+        dt_raw = layers.rmsnorm(params["dt_norm"], dt_raw, eps)
+        b_mat = layers.rmsnorm(params["b_norm"], b_mat, eps)
+        c_mat = layers.rmsnorm(params["c_norm"], c_mat, eps)
+    dt = jax.nn.softplus(
+        layers.linear(params["dt_proj"], dt_raw)
+        + params["dt_bias"].astype(x_conv.dtype)
+    ).astype(jnp.float32)
+    return dt, b_mat, c_mat
 
-    abar, bx: (B, Q, C, N);  h0: (B, C, N).
-    h_t = abar_t * h_{t-1} + bx_t.  Returns (h over chunk, final h).
-    """
 
-    def combine(a, b):
-        a1, b1 = a
-        a2, b2 = b
-        return a1 * a2, a2 * b1 + b2
-
-    acc_a, acc_b = jax.lax.associative_scan(combine, (abar, bx), axis=1)
-    h = acc_a * h0[:, None] + acc_b
-    return h, h[:, -1]
+def _gated(y: Array, z: Array) -> Array:
+    """The mixer's output gate, y * silu(z), in float32."""
+    return y * jax.nn.silu(z.astype(jnp.float32))
 
 
 def mamba_forward(
@@ -102,15 +112,15 @@ def mamba_forward(
     x: Array,
     mc: MambaConfig,
     *,
-    chunk_size: int = 256,
+    eps: float,
     initial_state: MambaState | None = None,
     return_state: bool = False,
 ) -> tuple[Array, MambaState | None]:
-    """Full-sequence mixer. x: (B, T, d_model) -> (B, T, d_model)."""
-    b, t, d_model = x.shape
+    """Full-sequence mixer. x: (B, T, d_model) -> (B, T, d_model); ``eps``
+    is the inner norms' (``mc.inner_norms``)."""
+    b, _, d_model = x.shape
     d_in = mc.expand * d_model
     rank = dt_rank_of(d_model, mc)
-    n = mc.d_state
 
     xz = layers.linear(params["in_proj"], x)
     x_in, z = jnp.split(xz, 2, axis=-1)
@@ -119,53 +129,15 @@ def mamba_forward(
     x_conv = jax.nn.silu(
         _causal_conv(x_in, params["conv_w"], params["conv_b"], conv_tail)
     )
-
-    proj = layers.linear(params["x_proj"], x_conv)
-    dt_raw, b_mat, c_mat = jnp.split(proj, [rank, rank + n], axis=-1)
-    dt = jax.nn.softplus(
-        layers.linear(params["dt_proj"], dt_raw)
-        + params["dt_bias"].astype(x.dtype)
-    ).astype(jnp.float32)                                   # (B, T, d_in)
+    dt, b_mat, c_mat = _ssm_inputs(params, x_conv, mc, rank, eps)
     a = -jnp.exp(params["A_log"].astype(jnp.float32))       # (d_in, N)
-
-    # chunked scan: sequential over chunks, associative within
-    q = min(chunk_size, t)
-    n_chunks = -(-t // q)
-    pad = n_chunks * q - t
-    def padt(arr):
-        return jnp.pad(arr, ((0, 0), (0, pad)) + ((0, 0),) * (arr.ndim - 2))
-    dt_c = padt(dt).reshape(b, n_chunks, q, d_in)
-    xc_c = padt(x_conv.astype(jnp.float32)).reshape(b, n_chunks, q, d_in)
-    b_c = padt(b_mat.astype(jnp.float32)).reshape(b, n_chunks, q, n)
-    c_c = padt(c_mat.astype(jnp.float32)).reshape(b, n_chunks, q, n)
-
     h0 = (
         initial_state.ssm
         if initial_state is not None
-        else jnp.zeros((b, d_in, n), jnp.float32)
+        else jnp.zeros((b, d_in, mc.d_state), jnp.float32)
     )
-
-    def chunk_step(h, inp):
-        dt_i, xc_i, b_i, c_i = inp  # (B, Q, ...)
-        abar = jnp.exp(dt_i[..., None] * a)                    # (B,Q,d_in,N)
-        bx = (dt_i * xc_i)[..., None] * b_i[:, :, None, :]     # (B,Q,d_in,N)
-        h_seq, h_last = _ssm_chunk(abar, bx, h)
-        y = jnp.einsum("bqcn,bqn->bqc", h_seq, c_i)            # (B,Q,d_in)
-        return h_last, y
-
-    h_final, ys = jax.lax.scan(
-        chunk_step,
-        h0,
-        (
-            jnp.moveaxis(dt_c, 1, 0),
-            jnp.moveaxis(xc_c, 1, 0),
-            jnp.moveaxis(b_c, 1, 0),
-            jnp.moveaxis(c_c, 1, 0),
-        ),
-    )
-    y = jnp.moveaxis(ys, 0, 1).reshape(b, n_chunks * q, d_in)[:, :t]
-    y = y + x_conv.astype(jnp.float32) * params["D"].astype(jnp.float32)
-    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+    y, h_final = selective_scan(dt, x_conv, b_mat, c_mat, a, params["D"], h0)
+    y = _gated(y, z).astype(x.dtype)
     out = layers.linear(params["out_proj"], y)
 
     state = None
@@ -184,13 +156,13 @@ def mamba_forward(
 
 
 def mamba_decode_step(
-    params: PyTree, x: Array, mc: MambaConfig, state: MambaState
+    params: PyTree, x: Array, mc: MambaConfig, state: MambaState, *,
+    eps: float
 ) -> tuple[Array, MambaState]:
     """One-token step. x: (B, 1, d_model)."""
     b, _, d_model = x.shape
     d_in = mc.expand * d_model
     rank = dt_rank_of(d_model, mc)
-    n = mc.d_state
 
     xz = layers.linear(params["in_proj"], x)
     x_in, z = jnp.split(xz, 2, axis=-1)       # (B, 1, d_in)
@@ -200,11 +172,8 @@ def mamba_decode_step(
     )
     new_conv = jnp.concatenate([state.conv, x_in], axis=1)[:, 1:]
 
-    proj = layers.linear(params["x_proj"], x_conv)
-    dt_raw, b_mat, c_mat = jnp.split(proj, [rank, rank + n], axis=-1)
-    dt = jax.nn.softplus(
-        layers.linear(params["dt_proj"], dt_raw) + params["dt_bias"].astype(x.dtype)
-    ).astype(jnp.float32)[:, 0]                # (B, d_in)
+    dt, b_mat, c_mat = _ssm_inputs(params, x_conv, mc, rank, eps)
+    dt = dt[:, 0]                              # (B, d_in)
     a = -jnp.exp(params["A_log"].astype(jnp.float32))
 
     abar = jnp.exp(dt[..., None] * a)          # (B, d_in, N)
@@ -214,7 +183,7 @@ def mamba_decode_step(
     h = abar * state.ssm + bx
     y = jnp.einsum("bcn,bn->bc", h, c_mat.astype(jnp.float32)[:, 0])
     y = y + x_conv.astype(jnp.float32)[:, 0] * params["D"].astype(jnp.float32)
-    y = (y[:, None] * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+    y = _gated(y[:, None], z).astype(x.dtype)
     out = layers.linear(params["out_proj"], y)
     return out, MambaState(conv=new_conv, ssm=h)
 

@@ -67,6 +67,8 @@ class Model:
 
     def _angles(self, batch: int, seq: int, offset, position_ids):
         cfg = self.cfg
+        if not cfg.rope:
+            return None
         if cfg.mrope:
             if position_ids is None:
                 position_ids = layers.text_position_ids(batch, seq, offset)

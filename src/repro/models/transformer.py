@@ -138,9 +138,11 @@ def _mixer_forward(
         return out, new_cache
     if spec.mixer == "mamba":
         if mode == "decode":
-            return mamba_mod.mamba_decode_step(bparams, x, cfg.mamba, cache)
+            return mamba_mod.mamba_decode_step(bparams, x, cfg.mamba, cache,
+                                               eps=cfg.norm_eps)
         return mamba_mod.mamba_forward(
-            bparams, x, cfg.mamba, return_state=(mode == "prefill")
+            bparams, x, cfg.mamba, eps=cfg.norm_eps,
+            return_state=(mode == "prefill")
         )
     if spec.mixer == "mlstm":
         if mode == "decode":

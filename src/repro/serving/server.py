@@ -75,7 +75,7 @@ from repro.core.transforms import (
 from repro.kernels import ops
 from repro.kernels.quantile_track import DeviceQuantileTracker
 from repro.serving.shadow import ShadowSink
-from repro.serving.spans import dispatched, span, timed
+from repro.serving.spans import dispatched, span, stamp, timed
 from repro.serving.tiering import (
     HostBankStore,
     ShardedTieredBankStore,
@@ -861,6 +861,7 @@ class MuseServer:
             with span("muse.models.features"):
                 feats = self._window_features(requests, idxs, fresh, dim)
                 pad = _shape_bucket(len(fresh)) - len(fresh)
+                stamp("model_bucket", len(fresh) + pad)
                 if pad:  # bucketed batch shape: no per-length recompiles
                     feats = np.concatenate(
                         [feats, np.zeros((pad,) + feats.shape[1:],

@@ -11,9 +11,11 @@ none running it is a shared no-op, a fraction of a microsecond.
 are added to that field of the window record the current thread has
 bound (``bind``).  The engine binds each window's ``window_log`` record
 around the window's stages, so the server's stage code stamps the window
-it serves without taking it as a parameter.  The same binding carries the
-engine's ``on_dispatch`` call, which the stage code makes through
-``dispatched()`` once the window's kernel is queued on the device.
+it serves without taking it as a parameter; ``stamp(field, value)`` sets
+a field of that record (the model stage's padded batch, ``model_bucket``).
+The same binding carries the engine's ``on_dispatch`` call, which the
+stage code makes through ``dispatched()`` once the window's kernel is
+queued on the device.
 
 Names stay bare (``muse.models.fetch``), with no per-call argument: the
 trace is reduced by event name.
@@ -92,6 +94,14 @@ class bind:
 
     def __exit__(self, *exc) -> None:
         _local.record, _local.on_dispatch = self._previous
+
+
+def stamp(field: str, value) -> None:
+    """Set ``field`` of the current thread's window record, if it has none
+    yet: the live call of a stage stamps before its shadows."""
+    record = getattr(_local, "record", None)
+    if record is not None:
+        record.setdefault(field, value)
 
 
 def dispatched() -> None:

@@ -123,3 +123,18 @@ def test_sharded_banked_launch_compiles(topo, monkeypatch):
     assert "tpu_custom_call" in text
     # per-shard programs: no collective moves a shard's rows elsewhere
     assert "all-gather" not in text and "all-to-all" not in text
+
+
+@pytest.mark.parametrize("b,t,c", [(64, 128, 5120), (1, 24, 256)])
+def test_selective_scan_compiles(one_chip, b, t, c):
+    """The Mamba scan at Jamba2-3B's widths (d_inner 5120, 16 states, 128
+    tokens, the largest window bucket) and at a small unaligned T."""
+    from repro.kernels.selective_scan import selective_scan
+    f32, n = jnp.float32, 16
+    args = (_spec((b, t, c), f32, one_chip), _spec((b, t, c), f32, one_chip),
+            _spec((b, t, n), f32, one_chip), _spec((b, t, n), f32, one_chip),
+            _spec((c, n), f32, one_chip), _spec((c,), f32, one_chip),
+            _spec((b, c, n), f32, one_chip))
+    text = _compiled_text(
+        lambda *a: selective_scan(*a, False), *args)
+    assert "tpu_custom_call" in text and "selective_scan" in text
