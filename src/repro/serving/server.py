@@ -16,6 +16,12 @@ the async engine (``serving/engine.py``) can pipeline them across windows:
   * :meth:`MuseServer.apply_transforms` — ONE banked T^C/A/T^Q kernel call
   * :meth:`MuseServer.track`            — quantile-estimator reservoir updates
 
+On a dense bank the first two stages meet on the device: ``run_models``
+queues the window's banked kernel on the forward's output and fetches raw
+and transformed scores in one transfer, and ``apply_transforms`` serves
+those scores unless the bank it resolves is no longer the one they ran
+under.
+
 Each stage reads served state through a :class:`_ControlPlane` snapshot —
 ONE attribute read yields a mutually consistent (predictors, banks,
 generation) triple, because every control-plane operation (deploy,
@@ -197,6 +203,16 @@ def _shape_bucket(n: int) -> int:
     return b
 
 
+def _edge_pad(tenant_idx: np.ndarray, n: int) -> np.ndarray:
+    """The tenant vector edge-padded to ``n`` rows, so an otherwise-uniform
+    tail block keeps the banked kernel's scalar-prefetch fast path (the
+    padded rows are sliced off)."""
+    pad = n - len(tenant_idx)
+    if not pad:
+        return tenant_idx
+    return np.concatenate([tenant_idx, np.full(pad, tenant_idx[-1], np.int32)])
+
+
 @dataclasses.dataclass(frozen=True)
 class _BankEntry:
     """A cached model-group bank pinned to the pipelines it was built from.
@@ -217,6 +233,30 @@ class _BankEntry:
     bank: TransformBank | None
     sharded: ShardedTransformBank | None = None
     tiered: TieredBankStore | ShardedTieredBankStore | None = None
+
+
+@dataclasses.dataclass(frozen=True)
+class _Chain:
+    """The banked kernel ``run_models`` queued on a window's forward
+    output: the dense bank entry and the (unpadded) tenant vector it ran
+    under, and its (bucket,) scores: on the device until ``run_models``
+    fetches them with the raw scores, on the host after."""
+
+    entry: _BankEntry
+    tenant_idx: np.ndarray
+    scores: Any
+
+
+class _ChainedRaws(np.ndarray):
+    """The (B, K) raw-score matrix ``run_models`` returns with its chained
+    kernel attached (``chain``).  Read-only, and the attachment stays on
+    this object alone: a view, a slice or a rebuilt array carries none,
+    so ``apply_transforms`` dispatches its own kernel for it."""
+
+    chain: _Chain | None = None
+
+    def __array_finalize__(self, obj) -> None:
+        self.chain = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -422,6 +462,10 @@ class MuseServer:
             "requests": 0, "kernel_dispatches": 0,
             "model_group_calls": 0, "model_calls": 0, "bank_generation": 0,
             "shard_dispatches": 0, "tier_dispatches": 0,
+            # dense windows whose kernel run_models queued behind the
+            # forward and apply_transforms served, and those whose chained
+            # kernel it dropped for the bank a publish brought meanwhile
+            "kernels_chained": 0, "kernels_redispatched": 0,
             # uniform-block fast-path coverage of the fused banked kernel:
             # blocks whose rows all share one tenant skip the one-hot gather
             # matmuls (see kernels/score_pipeline.py).  uniform/total over
@@ -842,6 +886,13 @@ class MuseServer:
         -> raw-score rows across dispatches of one batch, so live and shadow
         windows sharing a model group run the experts once (shadow dedup).
         Returns the (B, K) raw-score matrix.
+
+        When every row is fresh (no ``raw_cache`` hit) and the stage-time
+        ``plane`` gives the group a dense bank, the window's banked kernel
+        is queued on the forward's device output right behind the forward,
+        and raws and scores come back in one fetch.  The matrix then
+        carries the scores (:class:`_ChainedRaws`), which
+        ``apply_transforms`` takes in place of a dispatch of its own.
         """
         plane = self._plane if plane is None else plane
         pred0 = plane.predictors[pred_names[0]]
@@ -868,8 +919,15 @@ class MuseServer:
                                          np.float32)])
             with span("muse.models.forward"):
                 out = pred0.raw_scores(feats)
+                chain = self._chain_kernel(out, pred_names, plane) \
+                    if len(fresh) == len(idxs) else None
             with timed("muse.models.fetch", "model_fetch_ms"):
-                computed = np.asarray(out)[:len(fresh)]
+                if chain is None:
+                    computed = np.asarray(out)[:len(fresh)]
+                else:
+                    host, scores = jax.device_get((out, chain.scores))
+                    computed = host[:len(fresh)]
+                    chain = dataclasses.replace(chain, scores=scores)
             with self._metrics_lock:
                 self.metrics["model_group_calls"] += 1
                 self.metrics["model_calls"] += len(group)
@@ -877,7 +935,29 @@ class MuseServer:
                 rows[j] = computed[r]
                 if raw_cache is not None:
                     raw_cache[(group, idxs[j])] = computed[r]
+            if chain is not None:
+                raws = np.stack(rows).view(_ChainedRaws)
+                raws.flags.writeable = False  # the scores are of these rows
+                raws.chain = chain
+                return raws
         return np.stack(rows)                                # (B, K)
+
+    def _chain_kernel(self, out, pred_names: list[str],
+                      plane: _ControlPlane) -> _Chain | None:
+        """Queue the window's banked kernel on its forward's device output
+        ``out`` (bucket, K), against the dense bank ``plane`` resolves;
+        None under a tiered or sharded topology.  The returned chain holds
+        the device scores, not yet fetched."""
+        if self.config.tiering is not None \
+                or self._sharded_dispatch is not None:
+            return None
+        names = self.bank_names(pred_names, plane)
+        entry = self._bank_for(names, plane)
+        tenant_idx = self._tenant_vector(names, pred_names)
+        scores = self._dense_kernel(
+            entry.bank, jnp.asarray(out, jnp.float32),
+            _edge_pad(tenant_idx, out.shape[0]))
+        return _Chain(entry, tenant_idx, scores)
 
     def _window_features(self, requests, idxs: list[int], fresh: list[int],
                          dim: int) -> np.ndarray:
@@ -914,14 +994,26 @@ class MuseServer:
         window's provenance stamp.  On every topology, once the kernel call
         returns and before the result is fetched, ``spans.dispatched()``
         makes the ``on_dispatch`` call the engine bound to the window.
+
+        ``raws`` that ``run_models`` returned with a chained kernel are
+        served from its scores when this stage resolves the very bank entry
+        and tenant rows the chain ran under; otherwise (a publish since
+        stage 1, or an array without the chain) the kernel is dispatched
+        here.  The window record's ``kernel_chained`` stamp says which.
         """
         plane = self._plane if plane is None else plane
         with span("muse.transforms.bank"):
             bank_names = self.bank_names(pred_names, plane)  # cache key
             entry = self._bank_for(bank_names, plane)
-            row_of = {n: r for r, n in enumerate(bank_names)}
-            tenant_idx = np.asarray([row_of[n] for n in pred_names],
-                                    np.int32)
+            tenant_idx = self._tenant_vector(bank_names, pred_names)
+        chain = getattr(raws, "chain", None)
+        if chain is not None and (
+                chain.entry is not entry
+                or not np.array_equal(chain.tenant_idx, tenant_idx)):
+            # the chained kernel ran under a bank this stage no longer serves
+            self.bump_metric("kernels_redispatched")
+            chain = None
+        stamp("kernel_chained", int(chain is not None))
         if entry.tiered is not None:
             # tiered topology: slot-remapped banked dispatch against the
             # bounded device view; cold rows stage through the victim cache
@@ -948,25 +1040,17 @@ class MuseServer:
             self.bump_metric("shard_dispatches")
             return scores, bank, tenant_idx
         with span("muse.transforms.kernel"):
-            pad = _shape_bucket(b) - b
-            if pad:  # bucketed kernel shape, same reasoning as run_models
-                kraws = np.concatenate(
-                    [raws, np.zeros((pad,) + raws.shape[1:], raws.dtype)])
-                # edge-pad the tenant vector so an otherwise-uniform tail
-                # block keeps the kernel's scalar-prefetch fast path (rows
-                # sliced off)
-                kidx = np.concatenate(
-                    [tenant_idx, np.full(pad, tenant_idx[-1], np.int32)])
+            if chain is not None:
+                scores = chain.scores    # queued and fetched in stage 1
             else:
-                kraws, kidx = raws, tenant_idx
-            if self.config.fused_kernel:
-                scores = ops.score_pipeline_banked(
-                    jnp.asarray(kraws, jnp.float32), jnp.asarray(kidx),
-                    bank.betas, bank.weights,
-                    bank.src_quantiles, bank.ref_quantiles)
-            else:
-                scores = bank(jnp.asarray(kraws, jnp.float32),
-                              jnp.asarray(kidx))
+                pad = _shape_bucket(b) - b
+                kraws = raws
+                if pad:  # bucketed kernel shape, same reasoning as run_models
+                    kraws = np.concatenate(
+                        [raws, np.zeros((pad,) + raws.shape[1:], raws.dtype)])
+                scores = self._dense_kernel(
+                    bank, jnp.asarray(kraws, jnp.float32),
+                    _edge_pad(tenant_idx, b + pad))
         # the kernel is queued: the engine may launch the next window's
         # forward behind it, before this stage blocks on the result
         dispatched()
@@ -980,9 +1064,29 @@ class MuseServer:
                 self.metrics["skip_blocks_uniform"] += stats["uniform_blocks"]
                 self.metrics["skip_blocks_total"] += stats["blocks"]
         self.bump_metric("kernel_dispatches")
+        if chain is not None:
+            self.bump_metric("kernels_chained")
         with timed("muse.transforms.fetch", "kernel_wait_ms"):
             scores = np.asarray(scores)[:b]
         return scores, bank, tenant_idx
+
+    @staticmethod
+    def _tenant_vector(bank_names: tuple[str, ...],
+                       pred_names: list[str]) -> np.ndarray:
+        """Each row's bank row: its predictor's place in ``bank_names``."""
+        row_of = {n: r for r, n in enumerate(bank_names)}
+        return np.asarray([row_of[n] for n in pred_names], np.int32)
+
+    def _dense_kernel(self, bank: TransformBank, kraws: jax.Array,
+                      kidx: np.ndarray) -> jax.Array:
+        """Queue the banked kernel (or, without ``fused_kernel``, the bank's
+        jnp oracle) on a bucket of float32 raws; the scores stay on the
+        device."""
+        if self.config.fused_kernel:
+            return ops.score_pipeline_banked(
+                kraws, jnp.asarray(kidx), bank.betas, bank.weights,
+                bank.src_quantiles, bank.ref_quantiles)
+        return bank(kraws, jnp.asarray(kidx))
 
     def track(self, requests: list[ScoringRequest], idxs: list[int],
               pred_names: list[str], raws: np.ndarray, bank: TransformBank,
@@ -1298,15 +1402,9 @@ class MuseServer:
                         np.zeros((s, b), np.int32), entry.sharded.betas,
                         entry.sharded.weights, entry.sharded.src_quantiles,
                         entry.sharded.ref_quantiles)
-                elif self.config.fused_kernel:
-                    bank = entry.bank
-                    np.asarray(ops.score_pipeline_banked(
-                        jnp.asarray(raws), jnp.asarray(tid), bank.betas,
-                        bank.weights, bank.src_quantiles,
-                        bank.ref_quantiles))
                 else:
-                    np.asarray(entry.bank(jnp.asarray(raws),
-                                          jnp.asarray(tid)))
+                    np.asarray(self._dense_kernel(entry.bank,
+                                                  jnp.asarray(raws), tid))
                 if self._tracker is not None:
                     with self._estimator_lock:
                         self._tracker.warm(b, entry.bank)
